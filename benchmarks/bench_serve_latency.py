@@ -59,6 +59,7 @@ from repro.analysis import contracts
 from repro.core import api, covariance as cov, ppic, ppitc, support
 from repro.data import synthetic
 from repro.launch.gp_serve import GPServer
+from repro.launch.mesh import make_mesh
 from repro.parallel.runner import (ShardMapRunner, VmapRunner,
                                    routed_capacity)
 from repro.serving import TenantScheduler
@@ -363,7 +364,7 @@ def run(quick: bool = False, smoke: bool = False):
     # --- kernel-impl sweep: dense vs pallas xcov vs fused, both runners ----
     run_impl_sweep(kfn, params, state, ds.X_test, batches, "vmap")
     if jax.device_count() > 1:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = make_mesh((jax.device_count(),), ("data",))
         sm_runner = ShardMapRunner(mesh=mesh, axis_name="data")
         if n % sm_runner.num_machines == 0:
             state_sm = ppitc.fit(kfn, params, ds.X, ds.y, S=S,

@@ -33,6 +33,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = args.quick or args.smoke
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     from benchmarks import (bench_complexity, bench_fault, bench_kernels,
                             bench_lm_smoke, bench_serve_latency,
                             bench_vary_data, bench_vary_machines,

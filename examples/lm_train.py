@@ -15,6 +15,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import ARCH_NAMES, smoke_config
 from repro.data.loader import TokenLoader
 from repro.launch import train as train_lib
+from repro.launch.mesh import make_mesh
 from repro.optim.adam import Adam, cosine_schedule
 
 
@@ -32,7 +33,7 @@ def main():
 
     cfg = smoke_config(args.arch).scaled(
         n_layers=max(smoke_config(args.arch).n_layers, 4))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     opt = Adam(lr=cosine_schedule(3e-3, warmup=20, total=args.steps),
                clip_norm=1.0, weight_decay=0.01)
     state = train_lib.init_state(jax.random.PRNGKey(0), cfg, opt,

@@ -60,6 +60,7 @@ from typing import Any, Callable, NamedTuple, Protocol, runtime_checkable
 import jax
 import numpy as np
 
+from repro.core import covariance, linalg
 from repro.parallel.runner import ROUTED_ALPHA
 
 
@@ -75,11 +76,12 @@ class FGPState(NamedTuple):
 
 
 class PITCState(NamedTuple):
-    """PITC/pPITC: everything global lives in S-space (eqs. 5-8)."""
+    """PITC/pPITC: everything global lives in S-space (eqs. 5-8), whitened
+    by L = chol K_SS: Sigma-dot-dot = L A_L A_Lᵀ Lᵀ (core/ppitc.py)."""
     S: jax.Array        # (s, d) support set
     Kss_L: jax.Array    # (s, s) chol K_SS
-    Sdd_L: jax.Array    # (s, s) chol Sigma-dot_DD  (eq. 6)
-    alpha: jax.Array    # (s,)   Sdd^{-1} ydd       (eq. 7 weights)
+    A_L: jax.Array      # (s, s) chol L^{-1} Sdd L^{-T}  (eq. 6, whitened)
+    w: jax.Array        # (s,)   A_L^{-1} L^{-1} ydd     (eq. 7 weights)
 
 
 class PICState(NamedTuple):
@@ -92,17 +94,13 @@ class PICState(NamedTuple):
     query batch happens to be composed."""
     S: jax.Array        # (s, d)
     Kss_L: jax.Array    # (s, s)
-    Sdd_L: jax.Array    # (s, s)
-    alpha: jax.Array    # (s,)    Sdd^{-1} ydd
+    A_L: jax.Array      # (s, s)  as PITCState
+    w: jax.Array        # (s,)    as PITCState
     Xb: jax.Array       # (M, b, d) data blocks
     yb: jax.Array       # (M, b)
-    Ksd: jax.Array      # (M, s, b) cached K_S,Dm
+    V: jax.Array        # (M, s, b) Kss_L^{-1} K_S,Dm (whitened, see ppic)
     C_L: jax.Array      # (M, b, b) chol Sigma_{DmDm|S}
     Wy: jax.Array       # (M, b)    C^{-1} y_m
-    ydot: jax.Array     # (M, s)    local summaries (eq. 3)
-    beta: jax.Array     # (M, s)    Kss^{-1} ydot_m
-    B: jax.Array        # (M, s, s) Kss^{-1} Sdot_m
-    Sdot: jax.Array     # (M, s, s) local summaries (eq. 4)
     centroids: jax.Array  # (M, d)  block centroids (query routing targets)
 
 
@@ -151,6 +149,16 @@ class StateStore(Protocol):
     def revive(self, machine: int) -> "StateStore": ...
 
     def to_state(self) -> Any: ...
+
+
+def state_device_count(state) -> int:
+    """How many devices the arrays of ``state`` span (1 for host arrays and
+    traced values)."""
+    devices = set()
+    for a in jax.tree.leaves(state):
+        if isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer):
+            devices |= a.devices()
+    return max(len(devices), 1)
 
 
 def check_machine_index(n_machines: int, machine: int) -> None:
@@ -429,6 +437,9 @@ class ServePlan:
     caches: Any = None
     stats: PlanStats = dataclasses.field(default_factory=PlanStats)
     _exec: dict = dataclasses.field(default_factory=dict)
+    # why the plan serves with another kernel than the one it was asked
+    # for (``GPMethod.plan``); None when it serves the requested kernel
+    kernel_note: str | None = None
 
     # -- ladder -------------------------------------------------------------
 
@@ -493,7 +504,7 @@ class ServePlan:
         zero-recompile hot-swap contract."""
         fn = self._exec.get(key)
         if fn is None:
-            inner = build()
+            inner = linalg.f32_matmuls(build())
             stats = self.stats
 
             def counted(*args):
@@ -570,6 +581,12 @@ class ServePlan:
         plan's executables and stats. Same treedef + leaf shapes -> every
         compiled program is reused (zero recompilation, probe-tested);
         changed shapes cost one re-trace per entry point on next use."""
+        if covariance.xla_kernel(self.kfn) is not self.kfn \
+                and state_device_count(state) > 1:
+            raise ValueError(
+                f"this plan serves with a Pallas kernel, which cannot run on "
+                f"a state spread over {state_device_count(state)} devices; "
+                f"build a new plan for it (method.plan / FittedGP.plan)")
         return dataclasses.replace(self, state=state,
                                    caches=self._rebuild_caches(state))
 
@@ -649,12 +666,27 @@ class GPMethod:
                 f"ServeSpec(cached_cinv=True) but method {self.name!r} has "
                 f"no backend-cache plan (only the PIC family serves from "
                 f"per-block C factors)")
+        # a state spread over several devices is served by one program
+        # over all of them, which XLA partitions; it cannot partition a
+        # Pallas (Mosaic) call, so such a plan builds its cross-covariances
+        # in XLA and records why
+        note = None
+        n_dev = state_device_count(state)
+        requested = spec.resolve_kfn(kfn)
+        if n_dev > 1 and covariance.xla_kernel(requested) is not requested:
+            spec = dataclasses.replace(
+                spec, kernel=covariance.xla_kernel(requested))
+            note = (f"XLA cross-covariances: the state spans {n_dev} "
+                    f"devices and a Pallas kernel cannot be partitioned "
+                    f"across them")
         if self.plan_fn is not None:
-            return self.plan_fn(self, kfn, params, state, spec)
-        served = spec.resolve_kfn(kfn)
-        return ServePlan(self, served, params, state, spec,
-                         spec.resolve_block_q(kfn),
-                         spec.resolve_buckets(kfn))
+            plan = self.plan_fn(self, kfn, params, state, spec)
+        else:
+            plan = ServePlan(self, spec.resolve_kfn(kfn), params, state,
+                             spec, spec.resolve_block_q(kfn),
+                             spec.resolve_buckets(kfn))
+        return plan if note is None else dataclasses.replace(
+            plan, kernel_note=note)
 
 
 REGISTRY: dict[str, GPMethod] = {}

@@ -178,17 +178,33 @@ class KernelSpec:
 
     def fused_diag(self, params: dict, U: jax.Array, Xk: jax.Array,
                    L1: jax.Array, alpha: jax.Array,
-                   L2: jax.Array | None = None):
-        """Dispatch the fused serving kernel: (mean, var) with
-        var = sig2 - q(L1) [+ q(L2)] over lengthscale-scaled inputs."""
+                   A2: jax.Array | None = None):
+        """Dispatch the fused serving kernel over lengthscale-scaled inputs
+        (``rbf_ops.xcov_diag``; ``A2`` is the whitened second factor)."""
         from repro.kernels.rbf import ops as rbf_ops
         return rbf_ops.xcov_diag(
             _scale(params, U), _scale(params, Xk), L1, alpha,
-            signal_var(params), L2, impl=self.resolved_impl(),
+            signal_var(params), A2, impl=self.resolved_impl(),
             block_q=self.block_q)
 
 
 _IMPLS = ("auto", "pallas", "pallas_interpret", "jnp")
+
+
+def xla_kernel(kfn):
+    """``kfn`` with its cross-covariances built by XLA instead of the
+    compiled Pallas kernel, or ``kfn`` itself when it runs no such kernel.
+
+    XLA partitions a program whose arrays span several devices; a Mosaic
+    (Pallas TPU) call it cannot partition outside ``shard_map``, so a
+    serving plan over such a state takes this kernel
+    (``api.GPMethod.plan``)."""
+    if kfn is se_ard_pallas:
+        return se_ard
+    if isinstance(kfn, KernelSpec) and kfn.name in _SE_FAMILY \
+            and kfn.resolved_impl() == "pallas":
+        return dataclasses.replace(kfn, impl="jnp")
+    return kfn
 
 
 def make_spec(name: str = "se", *, impl: str = "auto", fused: bool = True,

@@ -27,6 +27,7 @@ class GPPosterior(NamedTuple):
         return jnp.diag(self.cov)
 
 
+@linalg.f32_matmuls
 def fit(kfn: cov.KernelFn, params: dict, X_train: jax.Array,
         y_train: jax.Array, **_) -> api.FGPState:
     """Cache chol(K_DD + noise) and its solve against y (zero prior mean)."""
@@ -36,6 +37,7 @@ def fit(kfn: cov.KernelFn, params: dict, X_train: jax.Array,
     return api.FGPState(X_train, L, alpha)
 
 
+@linalg.f32_matmuls
 def predict_batch(kfn: cov.KernelFn, params: dict, state: api.FGPState,
                   X_test: jax.Array, *, diag_only: bool = False) -> GPPosterior:
     """Eqs. (1)-(2) from the cached factors: no |D|^3 work per query."""
@@ -49,6 +51,7 @@ def predict_batch(kfn: cov.KernelFn, params: dict, state: api.FGPState,
     return GPPosterior(mean, K_uu - V.T @ V)
 
 
+@linalg.f32_matmuls
 def predict_batch_diag(kfn, params, state: api.FGPState, X_test):
     """(mean, var) vectors — no |U|x|U| intermediates (serving hot path).
 
@@ -64,6 +67,7 @@ def predict_batch_diag(kfn, params, state: api.FGPState, X_test):
     return mean, var
 
 
+@linalg.f32_matmuls
 def predict(kfn: cov.KernelFn, params: dict,
             X_train: jax.Array, y_train: jax.Array, X_test: jax.Array,
             mean_fn=None, *, diag_only: bool = False) -> GPPosterior:
@@ -88,6 +92,7 @@ def predict(kfn: cov.KernelFn, params: dict,
     return GPPosterior(mean, K_uu - V.T @ V)
 
 
+@linalg.f32_matmuls
 def nlml(kfn: cov.KernelFn, params: dict,
          X_train: jax.Array, y_train: jax.Array, mean_fn=None) -> jax.Array:
     """Negative log marginal likelihood -log p(y_D | theta) for MLE."""

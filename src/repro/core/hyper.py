@@ -32,7 +32,7 @@ def pitc_nlml_machine(kfn, params, S, Xm, ym, *, axis_name) -> jax.Array:
     n_m = Xm.shape[0]
     Kss = kfn(params, S, S)
     Kss_L = linalg.chol(Kss)
-    local, (Ksd, C_L, Wy) = local_summary(kfn, params, S, Kss_L, Xm, ym)
+    local, (_, _, C_L, Wy) = local_summary(kfn, params, S, Kss_L, Xm, ym)
     quad_m = ym @ Wy                                      # y C^{-1} y
     ydot_m, Sdot_m = local.ydot, local.Sdot
     logdet_m = linalg.logdet_from_chol(C_L)

@@ -32,6 +32,7 @@ class ICFFactor(NamedTuple):
     residual: jax.Array  # (n,) remaining diagonal residual (trace error)
 
 
+@linalg.f32_matmuls
 def icf_factor(kfn, params, X: jax.Array, R: int) -> ICFFactor:
     """Pivoted incomplete Cholesky of the signal kernel matrix."""
     n = X.shape[0]
@@ -70,6 +71,7 @@ def icf_predict_literal(kfn, params, X_train, y_train, X_test,
     return GPPosterior(mean, covm)
 
 
+@linalg.f32_matmuls
 def icf_predict(kfn, params, X_train, y_train, X_test,
                 F: jax.Array) -> GPPosterior:
     """Woodbury form — O(R^2 |D| + R |U| |D|), Table 1 row "ICF-based"."""

@@ -12,6 +12,27 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# Every f32 matmul of the GP pipeline runs at full f32 precision. On TPU
+# an f32 dot that names no precision is a single bf16 pass: the kernel
+# matrices lose positive-definiteness and the Cholesky pipeline (and the
+# served mean/variance) turn non-finite at the paper's |S| = 2048.
+MATMUL_PRECISION = "highest"
+
+
+def f32_matmuls(fn):
+    """Decorator: run ``fn`` with every matmul that names no precision of
+    its own at ``MATMUL_PRECISION``. The precision is recorded when ``fn``
+    is traced, so a jitted caller's compiled program keeps it. Applied to
+    the per-machine programs (``Runner.map``), the serving executables
+    (``ServePlan``), the S-space assembly between them and the public fit
+    and predict functions of the GP methods."""
+    @functools.wraps(fn)
+    def pinned(*args, **kwargs):
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return fn(*args, **kwargs)
+    return pinned
+
+
 # Jitter scaled to dtype: float64 paths need far less regularisation.
 _JITTER = {jnp.float64.dtype: 1e-10, jnp.float32.dtype: 1e-6}
 

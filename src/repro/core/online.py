@@ -14,11 +14,15 @@ Two layers here:
 
 * ``SummaryStore`` — the pure-array pytree of stacked per-machine summaries
   (cheap: M x (|S| + |S|² + |S|·b)) PLUS the incrementally-maintained global
-  factors. Every local summary Σ-dot_SS^m is PSD with the explicit low-rank
-  factor F_m = K_SDm chol(Σ_{DmDm|S})^{-T} (Σ-dot^m = F_m F_mᵀ), so folding a
-  machine in/out is a rank-b Cholesky update/downdate of ``Sdd_L``
-  (``linalg.chol_update_rank``) — O(|S|²·b) instead of the O(|S|³)
-  re-factorization, which makes ``to_state`` an O(|S|²) solve.
+  factor, kept whitened by L = chol K_SS. Every local summary Σ-dot_SS^m is
+  PSD with the explicit low-rank factor F_m = K_SDm chol(Σ_{DmDm|S})^{-T}
+  (Σ-dot^m = F_m F_mᵀ); the store keeps G_m = L⁻¹F_m, so Σ-dot-dot =
+  L (I + Σ_m G_m G_mᵀ) Lᵀ and folding a machine in/out is a rank-b Cholesky
+  update/downdate of ``A_L = chol(I + Σ_m G_m G_mᵀ)``
+  (``linalg.chol_update_rank``) — O(|S|²·b) instead of a
+  re-factorization, which makes ``to_state`` an O(|S|²) solve. Σ-dot-dot
+  itself has condition ~1e9 at the paper's scale (|D| = 32,000, |S| =
+  2,048), past a float32 Cholesky; A's eigenvalues are >= 1.
 * ``PITCStore`` / ``PICStore`` — the method-owned ``api.StateStore``
   implementations (registered via ``GPMethod.init_store`` by core/ppitc.py
   and core/ppic.py). ``PITCStore`` emits ``api.PITCState``; ``PICStore``
@@ -43,33 +47,40 @@ import numpy as np
 
 from repro.core import api, clustering, linalg
 from repro.core.ppitc import (GlobalSummary, LocalSummary, local_summary,
-                              predict_batch)
+                              predict_batch, whitened_factor)
 from repro.parallel.runner import Runner
 
 
 class SummaryStore(NamedTuple):
     locals_: LocalSummary     # stacked (M, ...) per-machine summaries
-    F: jax.Array              # (M, s, b) low-rank factors: Sdot_m = F_m F_mᵀ
+    G: jax.Array              # (M, s, b) whitened low-rank factors:
+    #                           Sdot_m = Kss_L G_m G_mᵀ Kss_Lᵀ
     alive: jax.Array          # (M,) bool — machine participation mask
     Kss: jax.Array            # (s, s) prior support covariance
     Kss_L: jax.Array          # (s, s) chol K_SS (static across mutations)
-    Sdd_L: jax.Array          # (s, s) chol of the ALIVE Σ-dot-dot (cached,
+    A_L: jax.Array            # (s, s) chol(I + Σ_alive G_m G_mᵀ): the ALIVE
+    #                           Σ-dot-dot whitened by Kss_L (cached,
     #                           maintained by rank-b updates — never refolded)
     ydd: jax.Array            # (s,)   alive Σ_m y-dot^m (cached)
 
 
-def _sdd_chol(Kss: jax.Array, Sdd: jax.Array) -> jax.Array:
-    """chol(Sdd + jitter·I) with the jitter anchored to K_SS.
+@linalg.f32_matmuls
+def _whitened_chol(G: jax.Array, alive: jax.Array | None = None
+                   ) -> jax.Array:
+    """chol(I + Σ_m G_m G_mᵀ) over the alive machines: Σ-dot-dot =
+    K_SS + jitter·I + Σ_m F_m F_mᵀ whitened by Kss_L.
 
-    Anchoring to the (mutation-invariant) prior scale instead of mean
-    diag(Sdd) makes the cold factorization and the incrementally-updated one
-    factor THE SAME matrix: assimilate/retire then differ from a full
+    The jitter is K_SS's own (the one in ``Kss_L``), a mutation-invariant
+    prior scale, so the cold factorization and the incrementally-updated
+    one factor THE SAME matrix: assimilate/retire then differ from a full
     recompute only by rank-update roundoff (~1e-13 in float64), not by a
     data-dependent jitter drift.
     """
-    scale = linalg.default_jitter(Sdd.dtype) * jnp.mean(jnp.diag(Kss))
-    return jnp.linalg.cholesky(
-        Sdd + scale * jnp.eye(Sdd.shape[-1], dtype=Sdd.dtype))
+    M, s, b = G.shape
+    if alive is not None:
+        G = G * alive.astype(G.dtype)[:, None, None]
+    G = jnp.moveaxis(G, 0, 1).reshape(s, M * b)
+    return jnp.linalg.cholesky(jnp.eye(s, dtype=G.dtype) + G @ G.T)
 
 
 def _summarize(kfn, params, S, X, y, runner: Runner):
@@ -78,24 +89,24 @@ def _summarize(kfn, params, S, X, y, runner: Runner):
 
     def fn(Xm, ym, params, S):
         Kss_L = linalg.chol(kfn(params, S, S))
-        loc, (Ksd, C_L, _) = local_summary(kfn, params, S, Kss_L, Xm, ym)
-        F = linalg.tri_solve(C_L, Ksd.T).T        # (s, b): Sdot = F Fᵀ
-        return loc, F
+        loc, (_, V, C_L, _) = local_summary(kfn, params, S, Kss_L, Xm, ym)
+        return loc, whitened_factor(V, C_L)
 
     return runner.map(fn, (Xb, yb), (params, S))
 
 
-def _pad_factor(F: jax.Array, b: int) -> jax.Array:
+def _pad_factor(G: jax.Array, b: int) -> jax.Array:
     """Zero-pad the block axis of an (M, s, b') factor to width b. Padded
-    columns contribute 0·0ᵀ to F Fᵀ, so the algebra is unchanged — this is
+    columns contribute 0·0ᵀ to G Gᵀ, so the algebra is unchanged — this is
     what lets waves of different block sizes share one stacked store."""
-    if F.shape[-1] >= b:
-        return F
-    return jnp.pad(F, [(0, 0), (0, 0), (0, b - F.shape[-1])])
+    if G.shape[-1] >= b:
+        return G
+    return jnp.pad(G, [(0, 0), (0, 0), (0, b - G.shape[-1])])
 
 
+@linalg.f32_matmuls
 def _cold_store(kfn, params, S, locals_: LocalSummary,
-                F: jax.Array) -> SummaryStore:
+                G: jax.Array) -> SummaryStore:
     """Assemble a SummaryStore from freshly-summarized blocks: the ONE
     place the global factor is Cholesky'd from scratch (cold O(|S|³), paid
     once per store lifetime) — shared by the PITC and PIC builders so both
@@ -103,19 +114,20 @@ def _cold_store(kfn, params, S, locals_: LocalSummary,
     alive = jnp.ones((locals_.ydot.shape[0],), bool)
     Kss = kfn(params, S, S)
     ydd = jnp.sum(locals_.ydot, axis=0)
-    Sdd_L = _sdd_chol(Kss, Kss + jnp.sum(locals_.Sdot, axis=0))
-    return SummaryStore(locals_, F, alive, Kss, linalg.chol(Kss), Sdd_L, ydd)
+    return SummaryStore(locals_, G, alive, Kss, linalg.chol(Kss),
+                        _whitened_chol(G), ydd)
 
 
 def build(kfn, params, S, X, y, runner: Runner) -> SummaryStore:
     """Initial store from blocked data (paper Steps 1-3)."""
-    locals_, F = _summarize(kfn, params, S, X, y, runner)
-    return _cold_store(kfn, params, S, locals_, F)
+    locals_, G = _summarize(kfn, params, S, X, y, runner)
+    return _cold_store(kfn, params, S, locals_, G)
 
 
+@linalg.f32_matmuls
 def global_summary(store: SummaryStore) -> GlobalSummary:
     """Assemble eqs. (5)-(6) from whatever machines are alive — the full
-    (non-incremental) reference the cached ``Sdd_L``/``ydd`` are tested
+    (non-incremental) reference the cached ``A_L``/``ydd`` are tested
     against; use it for arbitrary alive-mask views (``with_alive``)."""
     w = store.alive.astype(store.locals_.ydot.dtype)
     ydd = jnp.einsum("m,ms->s", w, store.locals_.ydot)
@@ -126,28 +138,30 @@ def global_summary(store: SummaryStore) -> GlobalSummary:
 def to_state(store: SummaryStore, S: jax.Array) -> api.PITCState:
     """Assemble the cached prediction factors (eqs. 7-8 precomputation).
 
-    O(|S|²): ``Sdd_L`` is maintained incrementally by assimilate/retire, so
-    only the weight solve remains here — the |S|³ factorization happens once
+    O(|S|²): ``A_L`` is maintained incrementally by assimilate/retire, so
+    only the two triangular solves of the whitened weights
+    w = A_L⁻¹ Kss_L⁻¹ ÿ remain here — the |S|³ factorization happens once
     at ``build`` and never again across the store's lifetime."""
-    alpha = linalg.chol_solve(store.Sdd_L, store.ydd[:, None])[:, 0]
-    return api.PITCState(S, store.Kss_L, store.Sdd_L, alpha)
+    c = linalg.tri_solve(store.Kss_L, store.ydd)
+    return api.PITCState(S, store.Kss_L, store.A_L,
+                         linalg.tri_solve(store.A_L, c))
 
 
 def _fold_in(store: SummaryStore, locals_new: LocalSummary,
-             F_new: jax.Array) -> SummaryStore:
+             G_new: jax.Array) -> SummaryStore:
     """Append new machine blocks and rank-update the cached global factors."""
-    b = max(store.F.shape[-1], F_new.shape[-1])
+    b = max(store.G.shape[-1], G_new.shape[-1])
     merged = LocalSummary(
         jnp.concatenate([store.locals_.ydot, locals_new.ydot]),
         jnp.concatenate([store.locals_.Sdot, locals_new.Sdot]))
-    F = jnp.concatenate([_pad_factor(store.F, b), _pad_factor(F_new, b)])
+    G = jnp.concatenate([_pad_factor(store.G, b), _pad_factor(G_new, b)])
     alive = jnp.concatenate(
-        [store.alive, jnp.ones((F_new.shape[0],), bool)])
+        [store.alive, jnp.ones((G_new.shape[0],), bool)])
     # one rank-(M'·b) update: stack the new machines' factor columns
-    W = jnp.concatenate([f for f in F_new], axis=1)        # (s, M'·b)
-    Sdd_L = linalg.chol_update_rank(store.Sdd_L, W)
+    W = jnp.concatenate([g for g in G_new], axis=1)        # (s, M'·b)
+    A_L = linalg.chol_update_rank(store.A_L, W)
     ydd = store.ydd + jnp.sum(locals_new.ydot, axis=0)
-    return SummaryStore(merged, F, alive, store.Kss, store.Kss_L, Sdd_L, ydd)
+    return SummaryStore(merged, G, alive, store.Kss, store.Kss_L, A_L, ydd)
 
 
 def assimilate(store: SummaryStore, kfn, params, S, X_new, y_new,
@@ -155,10 +169,11 @@ def assimilate(store: SummaryStore, kfn, params, S, X_new, y_new,
     """Fold a new data stream (D', y_D') in — Sec. 5.2.
 
     The new blocks are summarized in parallel and appended; old summaries
-    are reused untouched, and the global factor is advanced by a rank-b
-    Cholesky update per new block — O(|S|²·b) each, no |S|³ anywhere."""
-    locals_new, F_new = _summarize(kfn, params, S, X_new, y_new, runner)
-    return _fold_in(store, locals_new, F_new)
+    are reused untouched, and the global factor is advanced by one
+    rank-(M'·b) Cholesky update — O(|S|²·b) per new block, no |S|³
+    anywhere."""
+    locals_new, G_new = _summarize(kfn, params, S, X_new, y_new, runner)
+    return _fold_in(store, locals_new, G_new)
 
 
 def retire(store: SummaryStore, machine: int) -> SummaryStore:
@@ -174,9 +189,9 @@ def retire(store: SummaryStore, machine: int) -> SummaryStore:
             "refold path traces")
     if not alive[machine]:
         return store
-    Sdd_L = linalg.chol_update_rank(store.Sdd_L, store.F[machine], sign=-1.0)
+    A_L = linalg.chol_update_rank(store.A_L, store.G[machine], sign=-1.0)
     return store._replace(alive=store.alive.at[machine].set(False),
-                          Sdd_L=Sdd_L,
+                          A_L=A_L,
                           ydd=store.ydd - store.locals_.ydot[machine])
 
 
@@ -192,9 +207,9 @@ def revive(store: SummaryStore, machine: int) -> SummaryStore:
             "refold path traces")
     if alive[machine]:
         return store
-    Sdd_L = linalg.chol_update_rank(store.Sdd_L, store.F[machine])
+    A_L = linalg.chol_update_rank(store.A_L, store.G[machine])
     return store._replace(alive=store.alive.at[machine].set(True),
-                          Sdd_L=Sdd_L,
+                          A_L=A_L,
                           ydd=store.ydd + store.locals_.ydot[machine])
 
 
@@ -204,18 +219,23 @@ def with_alive(store: SummaryStore, alive: jax.Array, *,
     once) — the one sanctioned way to set ``alive`` wholesale (a raw
     ``_replace`` would desynchronize the cache). Two realizations:
 
-    * ``incremental`` — one rank-b cholupdate/downdate per FLIPPED machine
-      (retire/revive chain): O(|S|²·b·h) for Hamming distance h, so a small
-      deadline flip costs O(|S|²·b) — no |S|³ anywhere;
-    * ``refold``      — re-derive the factors from the masked summary sum in
-      one O(|S|³) pass (the cold-factorization float path).
+    * ``incremental`` — one rank-b cholupdate/downdate of ``A_L`` per
+      FLIPPED machine (retire/revive chain): O(|S|²·b·h) for Hamming
+      distance h, so a small deadline flip costs O(|S|²·b) — no |S|³
+      anywhere;
+    * ``refold``      — re-derive ``A_L`` from the alive machines' factors
+      in one pass (``_whitened_chol``, the cold-factorization float path):
+      the Gram product of the alive factors, O(|S|²·M·b), then an
+      O(|S|³/3) factorization.
 
-    ``mode="auto"`` picks by the Hamming distance of the mask against the
-    cost crossover: h·b rank-1 sweeps at O(|S|²) each versus the refold's
-    O(|S|³)/3 factorization plus the O(M·|S|²) masked re-sum — incremental
-    wins while h·b <= |S|/3 + M. Both paths produce the same matrix; they
-    differ only in float path (rank-update roundoff ~1e-13 in float64,
-    tests/test_state_store.py).
+    ``mode="auto"`` picks by the Hamming distance of the mask: the
+    incremental chain while h·b <= |S|/3 + M, the refold past it. The rule
+    counts h·b rank-1 sweeps at O(|S|²) each against the refold's
+    factorization; it takes the refold's Gram product as cheap next to the
+    sweeps, since it is one matmul where each sweep is a chain of |S|
+    dependent column steps (the crossover is not timed on a chip). Both
+    paths produce the same matrix; they differ only in float path
+    (rank-update roundoff ~1e-13 in float64, tests/test_state_store.py).
     """
     alive = jnp.asarray(alive, bool)
     if mode not in ("auto", "incremental", "refold"):
@@ -233,8 +253,8 @@ def with_alive(store: SummaryStore, alive: jax.Array, *,
     if mode != "refold":
         flips = np.flatnonzero(np.asarray(store.alive) != np.asarray(alive))
         if mode == "auto":
-            s = store.Sdd_L.shape[0]
-            b = store.F.shape[-1]
+            s = store.A_L.shape[0]
+            b = store.G.shape[-1]
             M = store.alive.shape[0]
             mode = ("incremental" if len(flips) * b <= s // 3 + M
                     else "refold")
@@ -244,11 +264,11 @@ def with_alive(store: SummaryStore, alive: jax.Array, *,
             store = revive(store, m) if bool(alive[m]) else retire(store, m)
         return store
     store = store._replace(alive=alive)
-    glob = global_summary(store)
-    return store._replace(Sdd_L=_sdd_chol(store.Kss, glob.Sdd),
-                          ydd=glob.ydd)
+    return store._replace(A_L=_whitened_chol(store.G, alive),
+                          ydd=global_summary(store).ydd)
 
 
+@linalg.f32_matmuls
 def replace_block(store: SummaryStore, kfn, params, S, machine: int,
                   Xm, ym) -> SummaryStore:
     """Recompute ONE machine's summary from its (re-read) data shard and
@@ -257,14 +277,14 @@ def replace_block(store: SummaryStore, kfn, params, S, machine: int,
     update."""
     api.check_machine_index(store.alive.shape[0], machine)
     store = retire(store, machine)
-    loc, (Ksd, C_L, _) = local_summary(kfn, params, S, store.Kss_L, Xm, ym)
-    F_m = linalg.tri_solve(C_L, Ksd.T).T
-    b = max(store.F.shape[-1], F_m.shape[-1])
-    F_m = _pad_factor(F_m[None], b)[0]
+    loc, (_, V, C_L, _) = local_summary(kfn, params, S, store.Kss_L, Xm, ym)
+    G_m = whitened_factor(V, C_L)
+    b = max(store.G.shape[-1], G_m.shape[-1])
+    G_m = _pad_factor(G_m[None], b)[0]
     locs = LocalSummary(store.locals_.ydot.at[machine].set(loc.ydot),
                         store.locals_.Sdot.at[machine].set(loc.Sdot))
-    store = store._replace(locals_=locs, F=_pad_factor(store.F, b)
-                           .at[machine].set(F_m))
+    store = store._replace(locals_=locs, G=_pad_factor(store.G, b)
+                           .at[machine].set(G_m))
     return revive(store, machine)
 
 
@@ -354,11 +374,9 @@ class PICBlocks(NamedTuple):
     global algebra lives in the shared SummaryStore. Leading axis M."""
     Xb: jax.Array      # (M, b, d)
     yb: jax.Array      # (M, b)
-    Ksd: jax.Array     # (M, s, b)
+    V: jax.Array       # (M, s, b) Kss_L⁻¹ K_SDm
     C_L: jax.Array     # (M, b, b)
     Wy: jax.Array      # (M, b)
-    beta: jax.Array    # (M, s)
-    B: jax.Array       # (M, s, s)
 
 
 def _summarize_pic(kfn, params, S, X, y, runner: Runner):
@@ -367,14 +385,11 @@ def _summarize_pic(kfn, params, S, X, y, runner: Runner):
 
     def fn(Xm, ym, params, S):
         Kss_L = linalg.chol(kfn(params, S, S))
-        loc, (Ksd, C_L, Wy) = local_summary(kfn, params, S, Kss_L, Xm, ym)
-        F = linalg.tri_solve(C_L, Ksd.T).T
-        beta = linalg.chol_solve(Kss_L, loc.ydot[:, None])[:, 0]
-        B = linalg.chol_solve(Kss_L, loc.Sdot)
-        return loc, F, Ksd, C_L, Wy, beta, B
+        loc, (_, V, C_L, Wy) = local_summary(kfn, params, S, Kss_L, Xm, ym)
+        return loc, whitened_factor(V, C_L), V, C_L, Wy
 
-    loc, F, Ksd, C_L, Wy, beta, B = runner.map(fn, (Xb, yb), (params, S))
-    return loc, F, PICBlocks(Xb, yb, Ksd, C_L, Wy, beta, B)
+    loc, G, V, C_L, Wy = runner.map(fn, (Xb, yb), (params, S))
+    return loc, G, PICBlocks(Xb, yb, V, C_L, Wy)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -414,12 +429,12 @@ class PICStore:
                 f"(b={X_new.shape[0] / M_new:g}) but the store's blocks are "
                 f"b={self.block_size}. Re-chunk the wave (or use the pPITC "
                 f"store, which accepts any block size).")
-        loc, F, blocks_new = _summarize_pic(self.kfn, self.params, self.S,
+        loc, G, blocks_new = _summarize_pic(self.kfn, self.params, self.S,
                                             X_new, y_new, runner)
         merged = PICBlocks(*(jnp.concatenate([a, b]) for a, b in
                              zip(self.blocks, blocks_new)))
         return dataclasses.replace(
-            self, store=_fold_in(self.store, loc, F), blocks=merged)
+            self, store=_fold_in(self.store, loc, G), blocks=merged)
 
     def retire(self, machine: int) -> "PICStore":
         new = retire(self.store, machine)
@@ -447,19 +462,17 @@ class PICStore:
             # streaming common case: no gather — every block cache (incl.
             # the full Xb dataset) is passed through by reference, keeping
             # update() at the advertised O(|S|² b)
-            blk, loc = self.blocks, st.locals_
+            blk = self.blocks
         else:
             idx = jnp.asarray(np.flatnonzero(np.asarray(st.alive)))
             blk = PICBlocks(*(a[idx] for a in self.blocks))
-            loc = LocalSummary(st.locals_.ydot[idx], st.locals_.Sdot[idx])
         return api.PICState(
-            self.S, glob.Kss_L, glob.Sdd_L, glob.alpha, blk.Xb, blk.yb,
-            blk.Ksd, blk.C_L, blk.Wy, loc.ydot, blk.beta, blk.B, loc.Sdot,
-            clustering.block_centroids(blk.Xb))
+            self.S, glob.Kss_L, glob.A_L, glob.w, blk.Xb, blk.yb,
+            blk.V, blk.C_L, blk.Wy, clustering.block_centroids(blk.Xb))
 
 
 def init_pic_store(kfn, params, X, y, *, S, runner: Runner) -> PICStore:
     """``GPMethod.init_store`` for ppic/pic (registered in core/ppic.py)."""
-    loc, F, blocks = _summarize_pic(kfn, params, S, X, y, runner)
+    loc, G, blocks = _summarize_pic(kfn, params, S, X, y, runner)
     return PICStore(kfn, params, S, runner,
-                    _cold_store(kfn, params, S, loc, F), blocks)
+                    _cold_store(kfn, params, S, loc, G), blocks)

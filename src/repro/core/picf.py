@@ -210,6 +210,7 @@ def fit(kfn, params, X, y, *, rank: int, runner: Runner) -> api.PICFState:
                            runner=runner).to_state()
 
 
+@linalg.f32_matmuls
 def predict_batch(kfn, params, state: api.PICFState, U, *,
                   diag_only: bool = False) -> GPPosterior:
     """Eqs. (20), (23)-(27) from the cached factor — no rank loop per query."""
@@ -234,6 +235,7 @@ def predict_batch(kfn, params, state: api.PICFState, U, *,
     return GPPosterior(mean, Kuu - Sig)
 
 
+@linalg.f32_matmuls
 def predict_batch_diag(kfn, params, state: api.PICFState, U):
     """(mean, var) vectors — no |U|x|U| intermediates (serving hot path)."""
     s2 = cov.noise_var(params)
@@ -324,6 +326,7 @@ class PICFStore:
         """Factor columns as Phi update vectors: Phi += (F/σ)(F/σ)ᵀ."""
         return Fm / jnp.sqrt(cov.noise_var(self.params))
 
+    @linalg.f32_matmuls
     def assimilate(self, X_new, y_new,
                    runner: Runner | None = None) -> "PICFStore":
         runner = runner or self.runner
@@ -350,6 +353,7 @@ class PICFStore:
             Phi_L=linalg.chol_update_rank(self.Phi_L, W),
             yF=self.yF + jnp.sum(jnp.einsum("mrb,mb->mr", F_new, yb_new), 0))
 
+    @linalg.f32_matmuls
     def retire(self, machine: int) -> "PICFStore":
         api.check_machine_index(self.alive.shape[0], machine)
         alive = api.concrete_alive_mask(self.alive)
@@ -368,6 +372,7 @@ class PICFStore:
                 self.Phi_L, self._scaled(self.F[machine]), sign=-1.0),
             yF=self.yF - self.F[machine] @ self.yb[machine])
 
+    @linalg.f32_matmuls
     def revive(self, machine: int) -> "PICFStore":
         api.check_machine_index(self.alive.shape[0], machine)
         alive = api.concrete_alive_mask(self.alive)
@@ -401,6 +406,7 @@ class PICFStore:
                              self.Phi_L, ydd)
 
 
+@linalg.f32_matmuls
 def init_picf_store(kfn, params, X, y, *, rank: int,
                     runner: Runner) -> PICFStore:
     """``GPMethod.init_store`` for picf: distributed ICF + cached R-space

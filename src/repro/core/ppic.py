@@ -5,8 +5,8 @@ summary with exact covariance against its own block (eqs. 12-14), recovering
 centralized PIC (Snelson 2007) exactly.
 
 Fit/predict split (core/api.py): ``fit`` caches, per block, the factors the
-local correction needs (Ksd, chol Sigma_{DmDm|S}, C^{-1}y, Kss^{-1}-projected
-summaries) plus the global S-space factors, in an ``api.PICState``. A
+local correction needs (V = chol(K_SS)^{-1} K_SDm, chol Sigma_{DmDm|S},
+C^{-1}y) plus the global S-space factors, in an ``api.PICState``. A
 repeated query batch then skips every O(b^3) local Cholesky — only
 cross-covariances and cached triangular solves remain. Two query-to-block
 assignment policies:
@@ -43,8 +43,8 @@ from repro.core import api, clustering
 from repro.core import covariance as cov
 from repro.core import linalg
 from repro.core.gp import GPPosterior
-from repro.core.ppitc import (GlobalSummary, LocalSummary, ParallelPosterior,
-                              global_summary, local_summary)
+from repro.core.ppitc import (ParallelPosterior, local_summary,
+                              whitened_summary)
 from repro.parallel.runner import (ROUTED_ALPHA, Runner, gather_by_block,
                                    gather_two_bucket, pad_blocks,
                                    routed_capacity, scatter_by_block,
@@ -52,48 +52,14 @@ from repro.parallel.runner import (ROUTED_ALPHA, Runner, gather_by_block,
 
 
 def machine_step(kfn, params, S, Xm, ym, Um, *, axis_name):
-    """Full pPIC per-machine program: steps 2-4 with local correction."""
+    """Full pPIC per-machine program: steps 2-4 with local correction —
+    eqs. (12)-(14) for the machine's query block, through the same
+    whitened forms as the cached-state path (``_block_posterior``)."""
     Kss_L = linalg.chol(kfn(params, S, S))
-    local, (Ksd, C_L, _) = local_summary(kfn, params, S, Kss_L, Xm, ym)
-    glob = global_summary(kfn, params, S, local, axis_name)
-    return predict_from_summary(kfn, params, S, Kss_L, local, glob,
-                                Xm, ym, Um, Ksd=Ksd, C_L=C_L)
-
-
-def predict_from_summary(kfn, params, S, Kss_L, local: LocalSummary,
-                         glob: GlobalSummary, Xm, ym, Um, *, Ksd=None,
-                         C_L=None):
-    """Eqs. (12)-(14). ``Ksd``/``C_L`` are reusable from local_summary."""
-    if Ksd is None:
-        Ksd = kfn(params, S, Xm)
-        V = linalg.tri_solve(Kss_L, Ksd)
-        Kdd = cov.add_noise(kfn(params, Xm, Xm), params)
-        C_L = linalg.chol(Kdd - V.T @ V)
-
-    Sdd_L = linalg.chol(glob.Sdd)
-    Kus = kfn(params, Um, S)
-    Kud = kfn(params, Um, Xm)                          # Sigma_{U_m D_m}
-
-    Wy = linalg.chol_solve(C_L, ym[:, None])[:, 0]     # C^{-1} y_m
-    ydot_u = Kud @ Wy                                  # y-dot_{U_m}^m
-    Wd = linalg.chol_solve(C_L, Kud.T)                 # C^{-1} K_{D_m U_m}
-    Sdot_su = Ksd @ Wd                                 # Sigma-dot_{S U_m}^m
-    Sdot_uu = Kud @ Wd                                 # Sigma-dot_{U_m U_m}^m
-
-    # eq. (14): Phi_{U_m S} = K_US + K_US Kss^{-1} Sdot_SS - Sdot_US
-    Phi = Kus + Kus @ linalg.chol_solve(Kss_L, local.Sdot) - Sdot_su.T
-
-    # eq. (12)
-    mean = (Phi @ linalg.chol_solve(Sdd_L, glob.ydd[:, None])[:, 0]
-            - Kus @ linalg.chol_solve(Kss_L, local.ydot[:, None])[:, 0]
-            + ydot_u)
-
-    # eq. (13), re-derived (Thm 2):
-    Kuu = kfn(params, Um, Um)
-    covm = Kuu - (Phi @ linalg.chol_solve(Kss_L, Kus.T)
-                  - Phi @ linalg.chol_solve(Sdd_L, Phi.T)
-                  - Kus @ linalg.chol_solve(Kss_L, Sdot_su)) - Sdot_uu
-    return mean, covm
+    _, (_, V, C_L, Wy) = local_summary(kfn, params, S, Kss_L, Xm, ym)
+    A_L, w = whitened_summary(V, C_L, Wy, axis_name)
+    return _block_posterior(kfn, params, api.PITCState(S, Kss_L, A_L, w),
+                            Um, (Xm, V, C_L, Wy))
 
 
 # ---------------------------------------------------------------------------
@@ -119,22 +85,44 @@ def init_store(kfn, params, X, y, *, S, runner: Runner):
     return online.init_pic_store(kfn, params, X, y, S=S, runner=runner)
 
 
+def _whitened_cross(kfn, params, state: api.PICState, Um, Xm, V):
+    """The cross terms of eqs. (12)-(14) for one query block, whitened by
+    L = chol K_SS: ``vT = K_{U S} L⁻ᵀ`` and the conditional
+    cross-covariance ``R = K_{U D_m} - vT V_m = Σ_{U D_m | S}``.
+
+    In these terms eq. (14) is Φ = K_{U S} - R C⁻¹ K_{D_m S}, i.e.
+    Φ L⁻ᵀ = vT - R C⁻¹ V_mᵀ; with zT = Φ L⁻ᵀ A_L⁻ᵀ the mean (12) is
+    zT w + R C⁻¹ y_m and the covariance (13) is
+    K_UU - vT vTᵀ - R C⁻¹ Rᵀ + zT zTᵀ. The textbook form
+    K_US + K_US K_SS⁻¹ Σ̇_SS - Σ̇_US subtracts terms far larger than Φ and
+    runs K_SS⁻¹ (condition ~1e5 at |S| = 2,048) through them: in float32 it
+    lost the posterior (variance errors ~1e-1 against float64 at |D| =
+    8,000, |S| = 2,048); this form only solves against factors whose
+    condition is the square root."""
+    vT = jax.lax.linalg.triangular_solve(
+        state.Kss_L, kfn(params, Um, state.S), left_side=False, lower=True,
+        transpose_a=True)                              # (u, s)
+    return vT, kfn(params, Um, Xm) - vT @ V
+
+
+def _global_rows(state: api.PICState, vT, P, V):
+    """zT = Φ L⁻ᵀ A_L⁻ᵀ (eq. 14 whitened twice) from the cross terms;
+    ``P`` is R C⁻¹. Its rows dot ``w`` to Φ Σ̈⁻¹ ÿ, and its row norms are
+    the diagonal of Φ Σ̈⁻¹ Φᵀ."""
+    return jax.lax.linalg.triangular_solve(
+        state.A_L, vT - P @ V.T, left_side=False, lower=True,
+        transpose_a=True)
+
+
 def _block_posterior(kfn, params, state: api.PICState, Um, m_fields):
     """Eqs. (12)-(14) for one query block from cached factors."""
-    Xm, ym, Ksd, C_L, Wy, ydot, beta, B = m_fields
-    Kus = kfn(params, Um, state.S)
-    Kud = kfn(params, Um, Xm)
-    rowdot = lambda A, v: jnp.sum(A * v[None, :], axis=1)
-    ydot_u = rowdot(Kud, Wy)
-    WdT = linalg.chol_solve_right(C_L, Kud)            # K_{U_m D_m} C^{-1}
-    Sdot_us = WdT @ Ksd.T                              # see the diag variant
-    Sdot_uu = WdT @ Kud.T
-    Phi = Kus + Kus @ B - Sdot_us                      # eq. (14)
-    mean = rowdot(Phi, state.alpha) - rowdot(Kus, beta) + ydot_u  # eq. (12)
-    Kuu = kfn(params, Um, Um)
-    covm = Kuu - (Phi @ linalg.chol_solve(state.Kss_L, Kus.T)
-                  - Phi @ linalg.chol_solve(state.Sdd_L, Phi.T)
-                  - Kus @ linalg.chol_solve(state.Kss_L, Sdot_us.T)) - Sdot_uu
+    Xm, V, C_L, Wy = m_fields
+    vT, R = _whitened_cross(kfn, params, state, Um, Xm, V)
+    P = linalg.chol_solve_right(C_L, R)                # R C⁻¹
+    zT = _global_rows(state, vT, P, V)
+    mean = zT @ state.w + R @ Wy                       # eq. (12)
+    covm = (kfn(params, Um, Um) - vT @ vT.T - R @ P.T  # eq. (13)
+            + zT @ zT.T)
     return mean, covm
 
 
@@ -142,7 +130,7 @@ def _block_posterior_diag(kfn, params, state: api.PICState, Um, m_fields):
     """Diagonal of eqs. (12)-(13) for one query block, no |U_m|^2 buffers.
 
     Every contraction keeps the query axis on matrix ROWS (row-wise
-    multiply-reduce instead of gemv, ``chol_solve_right`` instead of a
+    multiply-reduce instead of gemv, right-sided solves instead of a
     left-sided solve on Kᵀ, row-major gemms): XLA picks gemv/trsm/gemm
     panel strategies from the row count and total width, so a query-COLUMN
     formulation is not bitwise stable across slot positions or buffer
@@ -150,28 +138,28 @@ def _block_posterior_diag(kfn, params, state: api.PICState, Um, m_fields):
     property and the two-bucket layout's equivalence to the capacity-|U|
     layout (tests/test_routing_equivalence.py). Row-major forms are stable.
     """
-    Xm, ym, Ksd, C_L, Wy, ydot, beta, B = m_fields
-    Kus = kfn(params, Um, state.S)
-    Kud = kfn(params, Um, Xm)
-    rowdot = lambda A, v: jnp.sum(A * v[None, :], axis=1)
-    ydot_u = rowdot(Kud, Wy)
-    WdT = linalg.chol_solve_right(C_L, Kud)            # K_{U_m D_m} C^{-1}
-    Sdot_us = WdT @ Ksd.T                              # (u, s)
-    Phi = Kus + Kus @ B - Sdot_us
-    mean = rowdot(Phi, state.alpha) - rowdot(Kus, beta) + ydot_u
-    var = (cov.kdiag(kfn, params, Um)
-           - jnp.sum(Phi * linalg.chol_solve_right(state.Kss_L, Kus), 1)
-           + jnp.sum(Phi * linalg.chol_solve_right(state.Sdd_L, Phi), 1)
-           + jnp.sum(Kus * linalg.chol_solve_right(state.Kss_L, Sdot_us), 1)
-           - jnp.sum(Kud * WdT, 1))
+    Xm, V, C_L, Wy = m_fields
+    vT, R = _whitened_cross(kfn, params, state, Um, Xm, V)
+    return _local_diag(kfn, params, state, Um, vT, R,
+                       linalg.chol_solve_right(C_L, R), V, Wy)
+
+
+def _local_diag(kfn, params, state: api.PICState, Um, vT, R, P, V, Wy):
+    """(mean, var) of one query block from its whitened cross terms and
+    ``P = R C⁻¹`` (see ``_whitened_cross``)."""
+    rowdot = lambda A, B: jnp.sum(A * B, axis=1)
+    zT = _global_rows(state, vT, P, V)
+    mean = rowdot(zT, state.w[None, :]) + rowdot(R, Wy[None, :])
+    var = (cov.kdiag(kfn, params, Um) - rowdot(vT, vT) - rowdot(R, P)
+           + rowdot(zT, zT))
     return mean, var
 
 
 def _block_fields(state: api.PICState):
-    return (state.Xb, state.yb, state.Ksd, state.C_L, state.Wy, state.ydot,
-            state.beta, state.B)
+    return (state.Xb, state.V, state.C_L, state.Wy)
 
 
+@linalg.f32_matmuls
 def predict_blocks(kfn, params, state: api.PICState,
                    U) -> ParallelPosterior:
     """Block-layout posterior from cached state (|U| must divide M;
@@ -188,6 +176,7 @@ def predict_blocks(kfn, params, state: api.PICState,
     return ParallelPosterior(means.reshape(-1), covs)
 
 
+@linalg.f32_matmuls
 def predict_batch(kfn, params, state: api.PICState, U) -> GPPosterior:
     """Blockwise posterior from cached state for any |U|: pads the query
     batch to the block layout, assembles the dense block-diagonal
@@ -202,6 +191,7 @@ def predict_batch(kfn, params, state: api.PICState, U) -> GPPosterior:
     return GPPosterior(post.mean[:u], post.cov[:u, :u])
 
 
+@linalg.f32_matmuls
 def predict_batch_diag(kfn, params, state: api.PICState, U):
     """(mean, var) for any |U|: pads to the block layout, trims after."""
     M = state.Xb.shape[0]
@@ -230,8 +220,8 @@ def route_queries(state: api.PICState, U) -> jax.Array:
 def _block_posterior_diag_cinv(kfn, params, state: api.PICState, Um,
                                m_fields, Cinv_m):
     """``_block_posterior_diag`` with the per-block solve served from a
-    PRECOMPUTED dense inverse: ``K_{U_m D_m} C⁻¹`` is one row-major gemm
-    instead of the two-sided batched triangular solve.
+    PRECOMPUTED dense inverse: ``R C⁻¹`` is one row-major gemm instead of
+    the two-sided batched triangular solve.
 
     This is the plan-owned backend cache (``ServeSpec(cached_cinv=True)``):
     XLA-CPU (and small-RHS TPU) batched trsm bills per PROGRAM almost
@@ -243,25 +233,14 @@ def _block_posterior_diag_cinv(kfn, params, state: api.PICState, Um,
     Row-major throughout for the same composition-invariance reasons as
     ``_block_posterior_diag``.
     """
-    Xm, ym, Ksd, C_L, Wy, ydot, beta, B = m_fields
-    Kus = kfn(params, Um, state.S)
-    Kud = kfn(params, Um, Xm)
-    rowdot = lambda A, v: jnp.sum(A * v[None, :], axis=1)
-    ydot_u = rowdot(Kud, Wy)
-    WdT = Kud @ Cinv_m                                 # K_{U_m D_m} C^{-1}
-    Sdot_us = WdT @ Ksd.T                              # (u, s)
-    Phi = Kus + Kus @ B - Sdot_us
-    mean = rowdot(Phi, state.alpha) - rowdot(Kus, beta) + ydot_u
-    var = (cov.kdiag(kfn, params, Um)
-           - jnp.sum(Phi * linalg.chol_solve_right(state.Kss_L, Kus), 1)
-           + jnp.sum(Phi * linalg.chol_solve_right(state.Sdd_L, Phi), 1)
-           + jnp.sum(Kus * linalg.chol_solve_right(state.Kss_L, Sdot_us), 1)
-           - jnp.sum(Kud * WdT, 1))
-    return mean, var
+    Xm, V, C_L, Wy = m_fields
+    vT, R = _whitened_cross(kfn, params, state, Um, Xm, V)
+    return _local_diag(kfn, params, state, Um, vT, R, R @ Cinv_m, V, Wy)
 
 
 @no_retrace("ppic.cinv_blocks")
 @jax.jit
+@linalg.f32_matmuls
 def cinv_blocks(C_L: jax.Array) -> jax.Array:
     """(M, b, b) dense symmetric inverses ``(C_L C_Lᵀ)⁻¹`` per block — the
     one-time plan-build cost behind ``ServeSpec(cached_cinv=True)``; every
@@ -326,7 +305,7 @@ def global_diag(kfn, params, state: api.PICState, U):
     bounded-degradation serving path — accuracy degrades to pPITC, bounded
     by the ``with_alive`` refit oracle (tests/test_resilience.py)."""
     from repro.core import ppitc
-    gstate = api.PITCState(state.S, state.Kss_L, state.Sdd_L, state.alpha)
+    gstate = api.PITCState(state.S, state.Kss_L, state.A_L, state.w)
     return ppitc.predict_batch_diag(kfn, params, gstate, U)
 
 
@@ -351,6 +330,7 @@ def _routed_deg_program(kfn, params, state: api.PICState, Cinv, U, assign,
             jnp.where(dead_row, var_g, var_r))
 
 
+@linalg.f32_matmuls
 def predict_routed_diag(kfn, params, state: api.PICState, U, *,
                         alpha: int = ROUTED_ALPHA, tile: int | None = None):
     """Batch-composition-invariant (mean, var) for any |U|.
@@ -376,6 +356,7 @@ def predict_routed_diag(kfn, params, state: api.PICState, U, *,
                                 alpha=alpha, tile=tile, n_groups=None)
 
 
+@linalg.f32_matmuls
 def predict_routed_diag_capacity(kfn, params, state: api.PICState, U):
     """Capacity-|U| routed reference (the pre-two-bucket layout): every block
     gets a (|U|,)-slot buffer via ``scatter_by_block``. Kept as the oracle
@@ -391,6 +372,7 @@ def predict_routed_diag_capacity(kfn, params, state: api.PICState, U):
             gather_by_block(vars_, order, block_of, slot))
 
 
+@linalg.f32_matmuls
 def predict_routed(kfn, params, state: api.PICState, U) -> GPPosterior:
     """Routed posterior with the dense within-block covariance view.
 

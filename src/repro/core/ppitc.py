@@ -10,9 +10,12 @@ Per-machine program (runs under VmapRunner or ShardMapRunner):
   Step 4  each machine predicts its U_m slice (eqs. 7-8) locally.
 
 Fit/predict split (core/api.py): ``fit`` runs steps 1-3 through a Runner and
-caches the S-space factors in an ``api.PITCState`` (Kss_L, Sdd_L,
-alpha = Sdd^{-1} ydd); ``predict_batch`` is then O(|U||S| + |S|^2) per query
-batch — the real-time path. ``predict`` (legacy one-shot) is a thin wrapper
+caches the S-space factors in an ``api.PITCState``, whitened by
+L = chol K_SS: Kss_L, A_L = chol(L^{-1} Sdd L^{-T}) and the weights
+w = A_L^{-1} L^{-1} ydd; ``predict_batch`` is then O(|U||S|^2) per query
+batch with no |S|^3 work — the real-time path. Sdd itself has condition
+~1e9 at the paper's scale (|D| = 32,000, |S| = 2,048), past a float32
+Cholesky; its whitened form has eigenvalues >= 1. ``predict`` (legacy one-shot) is a thin wrapper
 over the two; ``predict_distributed`` keeps the fully-collective execution
 where prediction itself must stay on-device.
 
@@ -71,7 +74,8 @@ class ParallelPosterior(NamedTuple):
 
 def local_summary(kfn, params, S, Kss_L, Xm, ym):
     """Eqs. (3)-(4) with B=B'=S. Also returns the pieces pPIC/hyper reuse:
-    (Ksd, C_L = chol Sigma_{DmDm|S}, Wy = C^{-1} y_m)."""
+    (Ksd, V = Kss_L^{-1} K_SD_m, C_L = chol Sigma_{DmDm|S},
+    Wy = C^{-1} y_m)."""
     Ksd = kfn(params, S, Xm)                          # (s, b)
     V = linalg.tri_solve(Kss_L, Ksd)                  # Kss^{-1/2} K_SD_m
     Kdd = cov.add_noise(kfn(params, Xm, Xm), params)
@@ -79,35 +83,51 @@ def local_summary(kfn, params, S, Kss_L, Xm, ym):
     Wy = linalg.chol_solve(C_L, ym[:, None])[:, 0]    # C^{-1}(y - mu)
     ydot = Ksd @ Wy
     Sdot = Ksd @ linalg.chol_solve(C_L, Ksd.T)
-    return LocalSummary(ydot, Sdot), (Ksd, C_L, Wy)
+    return LocalSummary(ydot, Sdot), (Ksd, V, C_L, Wy)
 
 
-def global_summary(kfn, params, S, local: LocalSummary,
-                   axis_name) -> GlobalSummary:
-    """Eqs. (5)-(6): the single all-reduce of the algorithm."""
-    Kss = kfn(params, S, S)
-    ydd = jax.lax.psum(local.ydot, axis_name)
-    Sdd = Kss + jax.lax.psum(local.Sdot, axis_name)
-    return GlobalSummary(ydd, Sdd)
+def whitened_factor(V, C_L):
+    """One machine's G = L^{-1} F = V C_L^{-T} from its ``local_summary``
+    pieces (V = L^{-1} K_SD, C_L = chol Sigma_{DD|S}, L = chol K_SS), so
+    that Sdot = L G G^T L^T."""
+    return linalg.tri_solve(C_L, V.T).T
+
+
+def whitened_summary(V, C_L, Wy, axis_name):
+    """Eqs. (5)-(6) whitened by L = chol K_SS, from one machine's
+    ``local_summary`` pieces: (A_L, w) with A_L = chol(I + sum_m G_m G_m^T)
+    (L^{-1} Sdd L^{-T} = I + sum_m G_m G_m^T) and w = A_L^{-1} sum_m V_m Wy_m
+    (= A_L^{-1} L^{-1} ydd). The single all-reduce of the algorithm: one
+    |S|x|S| matrix and one |S|-vector."""
+    G = whitened_factor(V, C_L)                       # (s, b)
+    A = jnp.eye(V.shape[0], dtype=V.dtype) + jax.lax.psum(G @ G.T, axis_name)
+    A_L = jnp.linalg.cholesky(A)
+    return A_L, linalg.tri_solve(A_L, jax.lax.psum(V @ Wy, axis_name))
 
 
 def machine_step(kfn, params, S, Xm, ym, Um, *, axis_name):
     """Full pPITC per-machine program: steps 2-4. Returns (mean_m, cov_m)."""
     Kss_L = linalg.chol(kfn(params, S, S))
-    local, _ = local_summary(kfn, params, S, Kss_L, Xm, ym)
-    glob = global_summary(kfn, params, S, local, axis_name)
-    return predict_from_summary(kfn, params, S, Kss_L, glob, Um)
+    _, (_, V, C_L, Wy) = local_summary(kfn, params, S, Kss_L, Xm, ym)
+    A_L, w = whitened_summary(V, C_L, Wy, axis_name)
+    post = _posterior(kfn, params, api.PITCState(S, Kss_L, A_L, w), Um)
+    return post.mean, post.cov
 
 
-def predict_from_summary(kfn, params, S, Kss_L, glob: GlobalSummary, Um):
-    """Eqs. (7)-(8) — purely local given the global summary."""
-    Sdd_L = linalg.chol(glob.Sdd)
-    Kus = kfn(params, Um, S)
-    mean = Kus @ linalg.chol_solve(Sdd_L, glob.ydd[:, None])[:, 0]
-    Kuu = kfn(params, Um, Um)
-    covm = Kuu - Kus @ (linalg.chol_solve(Kss_L, Kus.T)
-                        - linalg.chol_solve(Sdd_L, Kus.T))
-    return mean, covm
+def _whitened_rows(kfn, params, state: api.PITCState, U):
+    """Query rows whitened: ``vT = K_US L^{-T}`` and ``zT = vT A_L^{-T}``,
+    so eq. (7) is zT w and eq. (8) is K_UU - vT vT^T + zT zT^T. Right-sided
+    solves keep the query axis on rows."""
+    solve = lambda Lf, B: jax.lax.linalg.triangular_solve(
+        Lf, B, left_side=False, lower=True, transpose_a=True)
+    vT = solve(state.Kss_L, kfn(params, U, state.S))  # (u, s)
+    return vT, solve(state.A_L, vT)
+
+
+def _posterior(kfn, params, state: api.PITCState, U) -> GPPosterior:
+    vT, zT = _whitened_rows(kfn, params, state, U)
+    return GPPosterior(zT @ state.w,
+                       kfn(params, U, U) - vT @ vT.T + zT @ zT.T)
 
 
 # ---------------------------------------------------------------------------
@@ -126,16 +146,13 @@ def fit(kfn, params, X, y, *, S, runner: Runner) -> api.PITCState:
     return online.to_state(online.build(kfn, params, S, X, y, runner), S)
 
 
+@linalg.f32_matmuls
 def predict_batch(kfn, params, state: api.PITCState, U) -> GPPosterior:
-    """Eqs. (7)-(8) from cached factors: O(|U||S| + |S|^2) per call."""
-    Kus = kfn(params, U, state.S)
-    mean = Kus @ state.alpha
-    Kuu = kfn(params, U, U)
-    covm = Kuu - Kus @ (linalg.chol_solve(state.Kss_L, Kus.T)
-                        - linalg.chol_solve(state.Sdd_L, Kus.T))
-    return GPPosterior(mean, covm)
+    """Eqs. (7)-(8) from cached factors: no |S|^3 work per call."""
+    return _posterior(kfn, params, state, U)
 
 
+@linalg.f32_matmuls
 def predict_batch_diag(kfn, params, state: api.PITCState, U):
     """(mean, var) without forming the |U|x|U| posterior covariance.
 
@@ -147,33 +164,22 @@ def predict_batch_diag(kfn, params, state: api.PITCState, U):
     the math it is validated against (tests/test_xcov_fused.py).
     """
     if isinstance(kfn, cov.KernelSpec) and kfn.fuse(state.S.shape[0]):
-        return kfn.fused_diag(params, U, state.S, state.Kss_L, state.alpha,
-                              L2=state.Sdd_L)
-    Kus = kfn(params, U, state.S)
-    mean = Kus @ state.alpha
-    A = linalg.chol_solve(state.Kss_L, Kus.T)         # Kss^{-1} K_SU
-    B = linalg.chol_solve(state.Sdd_L, Kus.T)         # Sdd^{-1} K_SU
-    var = (cov.kdiag(kfn, params, U)
-           - jnp.sum(Kus.T * A, axis=0) + jnp.sum(Kus.T * B, axis=0))
-    return mean, var
+        return kfn.fused_diag(params, U, state.S, state.Kss_L, state.w,
+                              A2=state.A_L)
+    vT, zT = _whitened_rows(kfn, params, state, U)
+    rowdot = lambda A: jnp.sum(A * A, axis=1)
+    return (zT @ state.w,
+            cov.kdiag(kfn, params, U) - rowdot(vT) + rowdot(zT))
 
 
+@linalg.f32_matmuls
 def predict_blocks(kfn, params, state: api.PITCState, U,
                    M: int) -> ParallelPosterior:
     """Per-machine prediction layout (step 4) from the cached state."""
     u = U.shape[0]
-    Ub = U.reshape(M, u // M, -1)
-
-    def one(Um):
-        Kus = kfn(params, Um, state.S)
-        mean = Kus @ state.alpha
-        Kuu = kfn(params, Um, Um)
-        covm = Kuu - Kus @ (linalg.chol_solve(state.Kss_L, Kus.T)
-                            - linalg.chol_solve(state.Sdd_L, Kus.T))
-        return mean, covm
-
-    means, covs = jax.vmap(one)(Ub)
-    return ParallelPosterior(means.reshape(u), covs)
+    post = jax.vmap(lambda Um: _posterior(kfn, params, state, Um))(
+        U.reshape(M, u // M, -1))
+    return ParallelPosterior(post.mean.reshape(u), post.cov)
 
 
 def predict(kfn, params, S, X, y, U, runner: Runner) -> ParallelPosterior:
@@ -194,6 +200,7 @@ def predict_distributed(kfn, params, S, X, y, U,
     return ParallelPosterior(runner.unshard(means), covs)
 
 
+@linalg.f32_matmuls
 def summaries(kfn, params, S, X, y, runner: Runner):
     """Stacked per-machine local summaries + the global summary.
 

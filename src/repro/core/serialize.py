@@ -41,11 +41,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import api
+from repro.core import api, linalg
 from repro.core import covariance as cov
 
-SCHEMA_VERSION = 1
-STORE_SCHEMA_VERSION = 1
+# v2: the pPITC/pPIC global factor is kept whitened by Kss_L (api.PITCState
+# A_L/w, the stores' G/A_L) and pPIC blocks cache V = Kss_L⁻¹ K_SD. v1 files
+# still load: the types whose layout changed are converted on load
+# (``_STATE_V1``/``_STORE_V1``, not bitwise), the others are unchanged.
+SCHEMA_VERSION = 2
+STORE_SCHEMA_VERSION = 2
 
 _FIELD = "field:"
 
@@ -118,6 +122,35 @@ for _cls in (api.FGPState, api.PITCState, api.PICState, api.PICFState):
     register_state(_cls)
 
 
+def _tri(L, B):
+    return jax.scipy.linalg.solve_triangular(L, B, lower=True)
+
+
+def _whiten_v1(a: dict, L_key: str, Sdd_key: str) -> dict:
+    """v1 -> v2 global factor: A_L = Kss_L⁻¹ Sdd_L (lower triangular with a
+    positive diagonal, and A_L A_Lᵀ = Kss_L⁻¹ Σ̈ Kss_L⁻ᵀ)."""
+    a[Sdd_key.replace("Sdd_L", "A_L")] = _tri(a[L_key], a.pop(Sdd_key))
+    return a
+
+
+@linalg.f32_matmuls
+def _pitc_state_v1(a: dict) -> dict:
+    a = _whiten_v1(a, "Kss_L", "Sdd_L")
+    # alpha = Σ̈⁻¹ÿ  ->  w = A_L⁻¹ Kss_L⁻¹ ÿ = A_Lᵀ Kss_Lᵀ alpha
+    a["w"] = a["A_L"].T @ (a["Kss_L"].T @ a.pop("alpha"))
+    return a
+
+
+def _pic_state_v1(a: dict) -> dict:
+    for k in ("ydot", "beta", "B", "Sdot"):
+        del a[k]
+    a["V"] = jax.vmap(lambda K: _tri(a["Kss_L"], K))(a.pop("Ksd"))
+    return _pitc_state_v1(a)
+
+
+_STATE_V1 = {"PITCState": _pitc_state_v1, "PICState": _pic_state_v1}
+
+
 def save_state(path, state) -> pathlib.Path:
     """Write a registered PosteriorState to ``path`` (npz). Returns the
     path actually written (always exactly ``path`` — no implicit .npz
@@ -154,7 +187,7 @@ def load_state(path):
         if "__schema__" not in z or "__state__" not in z:
             raise CheckpointError(path, "not a repro state checkpoint")
         schema = int(z["__schema__"])
-        if schema != SCHEMA_VERSION:
+        if schema not in (1, SCHEMA_VERSION):
             raise CheckpointError(
                 path, f"schema v{schema} != supported v{SCHEMA_VERSION}")
         name = str(z["__state__"])
@@ -163,16 +196,22 @@ def load_state(path):
                 path, f"unknown state type {name!r}; registered: "
                       f"{sorted(STATE_TYPES)}")
         cls = STATE_TYPES[name]
-        saved = {k[len(_FIELD):] for k in z.files if k.startswith(_FIELD)}
-        if saved != set(cls._fields):
+        arrays = {k: z[k] for k in z.files if k.startswith(_FIELD)}
+        _verify_checksums(path, z, arrays)
+        fields = {k[len(_FIELD):]: jnp.asarray(v) for k, v in arrays.items()}
+        if schema == 1 and name in _STATE_V1 \
+                and set(fields) != set(cls._fields):
+            try:
+                fields = _STATE_V1[name](dict(fields))
+            except KeyError:
+                pass          # not a v1 layout either: reported below
+        if set(fields) != set(cls._fields):
             raise CheckpointError(
                 path, f"field mismatch for {name}: file has "
-                      f"{sorted(saved)}, {name} expects "
+                      f"{sorted(fields)}, {name} expects "
                       f"{sorted(cls._fields)} (state schema drifted — "
                       f"migrate the checkpoint)")
-        arrays = {_FIELD + f: z[_FIELD + f] for f in cls._fields}
-        _verify_checksums(path, z, arrays)
-        return cls(*(jnp.asarray(arrays[_FIELD + f]) for f in cls._fields))
+        return cls(*(fields[f] for f in cls._fields))
 
 
 def peek(path) -> dict:
@@ -275,16 +314,16 @@ def _runner_from_meta(meta: dict, override):
 
 def _summary_arrays(s) -> dict:
     return {"sum:ydot": s.locals_.ydot, "sum:Sdot": s.locals_.Sdot,
-            "sum:F": s.F, "sum:alive": s.alive, "sum:Kss": s.Kss,
-            "sum:Kss_L": s.Kss_L, "sum:Sdd_L": s.Sdd_L, "sum:ydd": s.ydd}
+            "sum:G": s.G, "sum:alive": s.alive, "sum:Kss": s.Kss,
+            "sum:Kss_L": s.Kss_L, "sum:A_L": s.A_L, "sum:ydd": s.ydd}
 
 
 def _summary_from(arr) -> "object":
     from repro.core.online import SummaryStore
     from repro.core.ppitc import LocalSummary
     return SummaryStore(LocalSummary(arr["sum:ydot"], arr["sum:Sdot"]),
-                        arr["sum:F"], arr["sum:alive"], arr["sum:Kss"],
-                        arr["sum:Kss_L"], arr["sum:Sdd_L"], arr["sum:ydd"])
+                        arr["sum:G"], arr["sum:alive"], arr["sum:Kss"],
+                        arr["sum:Kss_L"], arr["sum:A_L"], arr["sum:ydd"])
 
 
 def _pitc_store_arrays(store) -> dict:
@@ -296,7 +335,7 @@ def _pitc_store_from(kfn, params, runner, arr):
     return PITCStore(kfn, params, arr["arr:S"], runner, _summary_from(arr))
 
 
-_PIC_BLOCK_FIELDS = ("Xb", "yb", "Ksd", "C_L", "Wy", "beta", "B")
+_PIC_BLOCK_FIELDS = ("Xb", "yb", "V", "C_L", "Wy")
 
 
 def _pic_store_arrays(store) -> dict:
@@ -326,8 +365,25 @@ def _picf_store_from(kfn, params, runner, arr):
                      *(arr[f"arr:{f}"] for f in _PICF_FIELDS))
 
 
-_SUM_KEYS = ("sum:ydot", "sum:Sdot", "sum:F", "sum:alive", "sum:Kss",
-             "sum:Kss_L", "sum:Sdd_L", "sum:ydd")
+_SUM_KEYS = ("sum:ydot", "sum:Sdot", "sum:G", "sum:alive", "sum:Kss",
+             "sum:Kss_L", "sum:A_L", "sum:ydd")
+
+
+def _summary_v1(a: dict) -> dict:
+    """v1 -> v2 summary: G = Kss_L⁻¹ F, A_L = Kss_L⁻¹ Sdd_L."""
+    L = a["sum:Kss_L"]
+    a["sum:G"] = jax.vmap(lambda F: _tri(L, F))(a.pop("sum:F"))
+    return _whiten_v1(a, "sum:Kss_L", "sum:Sdd_L")
+
+
+def _pic_store_v1(a: dict) -> dict:
+    del a["blk:beta"], a["blk:B"]
+    L = a["sum:Kss_L"]
+    a["blk:V"] = jax.vmap(lambda K: _tri(L, K))(a.pop("blk:Ksd"))
+    return _summary_v1(a)
+
+
+_STORE_V1 = {"PITCStore": _summary_v1, "PICStore": _pic_store_v1}
 
 # name -> (flatten, rebuild(kfn, params, runner, arrays), expected keys)
 STORE_TYPES: dict[str, tuple] = {
@@ -407,7 +463,7 @@ def load_store(path, *, kfn=None, runner=None, with_spec: bool = False):
                 path, "not a repro store checkpoint (state checkpoints "
                       "load via load_state)")
         schema = int(z["__store_schema__"])
-        if schema != STORE_SCHEMA_VERSION:
+        if schema not in (1, STORE_SCHEMA_VERSION):
             raise CheckpointError(
                 path, f"store schema v{schema} != supported "
                       f"v{STORE_SCHEMA_VERSION}")
@@ -422,6 +478,11 @@ def load_store(path, *, kfn=None, runner=None, with_spec: bool = False):
         _verify_checksums(path, z, raw)
         arr = {k: jnp.asarray(v) for k, v in raw.items()
                if not k.startswith(_PARAM)}
+        if schema == 1 and name in _STORE_V1 and set(arr) != set(expect):
+            try:
+                arr = _STORE_V1[name](dict(arr))
+            except KeyError:
+                pass          # not a v1 layout either: reported below
         if set(arr) != set(expect):
             raise CheckpointError(
                 path, f"field mismatch for {name}: file has "

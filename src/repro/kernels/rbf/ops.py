@@ -100,20 +100,22 @@ def _embed_tri_inv(L: jax.Array, s_pad: int) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_q"))
 def xcov_diag(Xq: jax.Array, Xk: jax.Array, L1: jax.Array, alpha: jax.Array,
-              sig2, L2: jax.Array | None = None, *, impl: str = "auto",
+              sig2, A2: jax.Array | None = None, *, impl: str = "auto",
               block_q: int | None = None):
     """Fused serving hot path over pre-scaled inputs: (mean, var) of the
     summary-method diag predict (see kernels/rbf/xcov.py) without the
     (n, |S|) HBM round-trip.
 
-    Xq: (n, d) queries, Xk: (s, d) support/training set, L1/L2: (s, s)
-    cached lower Cholesky factors (variance = sig2 - q(L1) [+ q(L2)]),
-    alpha: (s,) cached weights. impl as in ``rbf_covariance``.
+    Xq: (n, d) queries, Xk: (s, d) support/training set, L1/A2: (s, s)
+    cached lower Cholesky factors, alpha: (s,) cached weights. Without
+    ``A2``: mean = K_US alpha, var = sig2 - |L1⁻¹K_SU|². With it:
+    Z = A2⁻¹ L1⁻¹ K_SU, mean = Zᵀ alpha, var = sig2 - |L1⁻¹K_SU|² + |Z|²
+    (the whitened pPITC state). impl as in ``rbf_covariance``.
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
     if impl == "jnp":
-        return ref.xcov_diag(Xq, Xk, L1, alpha, sig2, L2)
+        return ref.xcov_diag(Xq, Xk, L1, alpha, sig2, A2)
 
     n, _ = Xq.shape
     s = Xk.shape[0]
@@ -125,13 +127,13 @@ def xcov_diag(Xq: jax.Array, Xk: jax.Array, L1: jax.Array, alpha: jax.Array,
             f"|S|={s} exceeds the fused kernel's VMEM residency cap "
             f"{MAX_FUSED_RESIDENT}; use the compose path (impl='jnp')")
     Xk_p = _pad_to(Xk_p, 0, s_pad)
-    with_l2 = L2 is not None
+    with_a2 = A2 is not None
     L1_p = _embed_tri_inv(L1, s_pad)
-    L2_p = _embed_tri_inv(L2, s_pad) if with_l2 else L1_p
+    A2_p = _embed_tri_inv(A2, s_pad) if with_a2 else L1_p
     alpha_p = _pad_to(alpha[None, :], 1, s_pad)
     bq = block_q or pick_serve_block_q(n)
     Xq_p = _pad_to(Xq_p, 0, bq)
-    mean, var = xcov_diag_pallas(Xq_p, Xk_p, L1_p, L2_p, alpha_p, sig2,
-                                 s_valid=s, with_l2=with_l2, block_q=bq,
+    mean, var = xcov_diag_pallas(Xq_p, Xk_p, L1_p, A2_p, alpha_p, sig2,
+                                 s_valid=s, with_a2=with_a2, block_q=bq,
                                  interpret=(impl == "pallas_interpret"))
     return mean[:n], var[:n]

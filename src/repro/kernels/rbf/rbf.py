@@ -27,13 +27,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# full f32 MXU contraction (Mosaic's fp32 contract precision): the distance
+# ||x||^2 + ||z||^2 - 2 x.z cancels, and one bf16 pass leaves K_SS indefinite
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _rbf_kernel(sig2_ref, xq_ref, xk_ref, out_ref):
     xq = xq_ref[...].astype(jnp.float32)          # (bq, d)
     xk = xk_ref[...].astype(jnp.float32)          # (bk, d)
     # MXU: cross terms; VPU: norms + exp
     cross = jax.lax.dot_general(
-        xq, xk, (((1,), (1,)), ((), ())),
+        xq, xk, (((1,), (1,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32)       # (bq, bk)
     q2 = jnp.sum(xq * xq, axis=-1)[:, None]
     k2 = jnp.sum(xk * xk, axis=-1)[None, :]

@@ -22,12 +22,13 @@ def rbf_covariance(Xq: jax.Array, Xk: jax.Array, sig2) -> jax.Array:
 
 
 def xcov_diag(Xq: jax.Array, Xk: jax.Array, L1: jax.Array, alpha: jax.Array,
-              sig2, L2: jax.Array | None = None):
+              sig2, A2: jax.Array | None = None):
     """Compose-path oracle for the fused serving kernel (xcov.py).
 
     Builds K_US dense, applies the cached triangular solves, reduces the
     variance quadratic form — the exact math ``ppitc.predict_batch_diag``
-    (L2 = chol Sdd) and ``gp.predict_batch_diag`` (L2 = None) perform, over
+    (A2 = the whitened Sdd factor, alpha the whitened weights) and
+    ``gp.predict_batch_diag`` (A2 = None) perform, over
     pre-lengthscale-scaled inputs. Accumulates in f32 for <=f32 inputs and
     f64 for f64, matching the kernel's accumulation dtype.
     """
@@ -38,13 +39,15 @@ def xcov_diag(Xq: jax.Array, Xk: jax.Array, L1: jax.Array, alpha: jax.Array,
     d2 = jnp.maximum(q2 + k2 - 2.0 * (Xqa @ Xka.T), 0.0)
     sig2 = jnp.asarray(sig2, acc)
     kus = sig2 * jnp.exp(-0.5 * d2)                    # (n, s)
-    mean = jnp.sum(kus * alpha.astype(acc)[None, :], axis=1)
     v1 = jax.lax.linalg.triangular_solve(
         L1.astype(acc), kus, left_side=False, lower=True, transpose_a=True)
     var = sig2 - jnp.sum(v1 * v1, axis=1)
-    if L2 is not None:
+    if A2 is None:
+        mean = jnp.sum(kus * alpha.astype(acc)[None, :], axis=1)
+    else:
         v2 = jax.lax.linalg.triangular_solve(
-            L2.astype(acc), kus, left_side=False, lower=True,
+            A2.astype(acc), v1, left_side=False, lower=True,
             transpose_a=True)
         var = var + jnp.sum(v2 * v2, axis=1)
+        mean = jnp.sum(v2 * alpha.astype(acc)[None, :], axis=1)
     return mean.astype(Xq.dtype), var.astype(Xq.dtype)

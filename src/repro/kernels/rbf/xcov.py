@@ -5,10 +5,13 @@ cached triangular solves + predictive-variance quadratic form in one pass.
 batch U against the cached state:
 
     K_US  = sig2 * exp(-0.5 ||u - s||^2)              (bq, |S|) tile
-    mean  = K_US @ alpha
-    var   = sig2 - ||L1^{-1} K_SU||^2_cols + ||L2^{-1} K_SU||^2_cols
+    V     = L1^{-1} K_SU,   Z = A2^{-1} V
+    mean  = Z^T alpha
+    var   = sig2 - ||V||^2_cols + ||Z||^2_cols
 
-with L1 = chol K_SS and L2 = chol Sdd (FGP drops the L2 term). The XLA
+with L1 = chol K_SS, A2 the whitened Sdd factor chol(L1^{-1} Sdd L1^{-T})
+and alpha the whitened weights (core/ppitc.py). FGP drops the second
+factor: mean = K_US @ alpha, var = sig2 - ||V||^2_cols, L1 = chol(K + s2 I). The XLA
 compose path materializes K_US in HBM and reads it back for each solve; this
 kernel keeps the (bq, |S|) tile in VMEM end to end — covariance assembly on
 the MXU, the cached triangular solves applied on-tile, and the
@@ -47,16 +50,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# full f32 MXU contraction (Mosaic's fp32 contract precision); the serving
+# variance is a difference of quadratic forms that one bf16 pass cannot hold
+_HIGHEST = jax.lax.Precision.HIGHEST
 
-def _xcov_diag_kernel(sig2_ref, xq_ref, xk_ref, l1inv_ref, l2inv_ref,
+
+def _xcov_diag_kernel(sig2_ref, xq_ref, xk_ref, l1inv_ref, a2inv_ref,
                       alpha_ref, mean_ref, var_ref, *, s_valid: int,
-                      with_l2: bool, acc_dtype):
+                      with_a2: bool, acc_dtype):
     xq = xq_ref[...].astype(acc_dtype)                 # (bq, d)
     xk = xk_ref[...].astype(acc_dtype)                 # (s_pad, d)
     sig2 = sig2_ref[0, 0].astype(acc_dtype)
     # MXU: cross term; VPU: norms + exp (fused RBF, see rbf.py)
     cross = jax.lax.dot_general(
-        xq, xk, (((1,), (1,)), ((), ())),
+        xq, xk, (((1,), (1,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=acc_dtype)              # (bq, s_pad)
     q2 = jnp.sum(xq * xq, axis=-1)[:, None]
     k2 = jnp.sum(xk * xk, axis=-1)[None, :]
@@ -66,31 +73,34 @@ def _xcov_diag_kernel(sig2_ref, xq_ref, xk_ref, l1inv_ref, l2inv_ref,
         kus = jnp.where(cols < s_valid, kus, 0.0)
 
     alpha = alpha_ref[...].astype(acc_dtype)           # (1, s_pad)
-    mean = jnp.sum(kus * alpha, axis=1)                # (bq,) row-reduce
     # cached triangular solve on-tile: V = K_US L^{-T} as an MXU gemm
     # against the VMEM-resident inverse (contract over L^{-1}'s columns);
     # the variance quadratic form is then a row-wise square-reduce
     v1 = jax.lax.dot_general(
         kus, l1inv_ref[...].astype(acc_dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=acc_dtype)              # (bq, s_pad)
-    var = sig2 - jnp.sum(v1 * v1, axis=1)
-    if with_l2:
+        precision=_HIGHEST, preferred_element_type=acc_dtype)  # (bq, s_pad)
+    var = sig2 - jnp.sum(v1 * v1, axis=1, keepdims=True)
+    if with_a2:
+        # the second solve chains on the first: Z = V A2^{-T}
         v2 = jax.lax.dot_general(
-            kus, l2inv_ref[...].astype(acc_dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype)
-        var = var + jnp.sum(v2 * v2, axis=1)
-    mean_ref[...] = mean[None, :].astype(mean_ref.dtype)
-    var_ref[...] = var[None, :].astype(var_ref.dtype)
+            v1, a2inv_ref[...].astype(acc_dtype), (((1,), (1,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=acc_dtype)
+        var = var + jnp.sum(v2 * v2, axis=1, keepdims=True)
+        mean = jnp.sum(v2 * alpha, axis=1, keepdims=True)
+    else:
+        mean = jnp.sum(kus * alpha, axis=1, keepdims=True)  # row-reduce
+    mean_ref[...] = mean.astype(mean_ref.dtype)
+    var_ref[...] = var.astype(var_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("s_valid", "with_l2", "block_q",
+@functools.partial(jax.jit, static_argnames=("s_valid", "with_a2", "block_q",
                                              "interpret"))
 def xcov_diag_pallas(Xq: jax.Array, Xk: jax.Array, L1inv: jax.Array,
-                     L2inv: jax.Array, alpha: jax.Array, sig2: jax.Array, *,
-                     s_valid: int, with_l2: bool = True, block_q: int = 128,
+                     A2inv: jax.Array, alpha: jax.Array, sig2: jax.Array, *,
+                     s_valid: int, with_a2: bool = True, block_q: int = 128,
                      interpret: bool = False):
     """Tiled fused serving kernel. Caller guarantees n % block_q == 0 and
-    Xk/L1inv/L2inv/alpha padded per the module contract — L1inv/L2inv are
+    Xk/L1inv/A2inv/alpha padded per the module contract — L1inv/A2inv are
     the lower-triangular INVERSE factors (ops.py does all of this).
     Returns ((n,) mean, (n,) var) in Xq's dtype."""
     n, d = Xq.shape
@@ -99,7 +109,7 @@ def xcov_diag_pallas(Xq: jax.Array, Xk: jax.Array, L1inv: jax.Array,
     sig2 = jnp.asarray(sig2, acc_dtype).reshape(1, 1)
     grid = (n // block_q,)
     kernel = functools.partial(_xcov_diag_kernel, s_valid=s_valid,
-                               with_l2=with_l2, acc_dtype=acc_dtype)
+                               with_a2=with_a2, acc_dtype=acc_dtype)
     mean, var = pl.pallas_call(
         kernel,
         grid=grid,
@@ -108,17 +118,21 @@ def xcov_diag_pallas(Xq: jax.Array, Xk: jax.Array, L1inv: jax.Array,
             pl.BlockSpec((block_q, d), lambda i: (i, 0)),      # Xq tile
             pl.BlockSpec((s_pad, d), lambda i: (0, 0)),        # support set
             pl.BlockSpec((s_pad, s_pad), lambda i: (0, 0)),    # L1^{-1}
-            pl.BlockSpec((s_pad, s_pad), lambda i: (0, 0)),    # L2^{-1}
+            pl.BlockSpec((s_pad, s_pad), lambda i: (0, 0)),    # A2^{-1}
             pl.BlockSpec((1, s_pad), lambda i: (0, 0)),        # alpha
         ],
+        # (n, 1) column outputs: a (block_q, 1) block meets the TPU tiling
+        # rule (block_q % 8 == 0, last dim equal to the array's) for every
+        # serving tile from 8 up; a (1, block_q) row block would need
+        # block_q % 128 == 0
         out_specs=[
-            pl.BlockSpec((1, block_q), lambda i: (0, i)),
-            pl.BlockSpec((1, block_q), lambda i: (0, i)),
+            pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, n), Xq.dtype),
-            jax.ShapeDtypeStruct((1, n), Xq.dtype),
+            jax.ShapeDtypeStruct((n, 1), Xq.dtype),
+            jax.ShapeDtypeStruct((n, 1), Xq.dtype),
         ],
         interpret=interpret,
-    )(sig2, Xq, Xk, L1inv, L2inv, alpha)
-    return mean[0], var[0]
+    )(sig2, Xq, Xk, L1inv, A2inv, alpha)
+    return mean[:, 0], var[:, 0]

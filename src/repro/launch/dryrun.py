@@ -29,7 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.configs.registry import ARCH_NAMES, get_config
 from repro.configs.shapes import SHAPES, applicable
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch import serve as serve_lib
 from repro.launch import train as train_lib
 from repro.models import transformer as tf
@@ -289,8 +289,8 @@ def _make_mesh(mesh_name: str):
         return make_production_mesh(multi_pod=multi), (512 if multi else 256)
     if multi:
         shape = (2, 2, n_dev // 4)
-        return jax.make_mesh(shape, ("pod", "data", "model")), n_dev
-    return jax.make_mesh((2, n_dev // 4), ("data", "model")), n_dev // 2
+        return make_mesh(shape, ("pod", "data", "model")), n_dev
+    return make_mesh((2, n_dev // 4), ("data", "model")), n_dev // 2
 
 
 def run_cell(arch: str, shape_name: str, mesh_name: str, *,

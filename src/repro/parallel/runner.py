@@ -20,12 +20,7 @@ from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-# jax.shard_map graduated from jax.experimental in 0.4.x; support both
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +37,8 @@ class Runner:
 
         ``sharded`` entries are pytrees whose leaves carry a leading (M, ...)
         machine axis; ``fn`` sees them without it. Returns stacked outputs.
+        ``fn``'s matmuls run at full f32 precision
+        (``core.linalg.f32_matmuls``).
         """
         raise NotImplementedError
 
@@ -81,7 +78,8 @@ class VmapRunner(Runner):
         return self.M
 
     def map(self, fn, sharded, replicated=()):
-        g = lambda *blocks: fn(*blocks, *replicated)
+        from repro.core.linalg import f32_matmuls
+        g = f32_matmuls(lambda *blocks: fn(*blocks, *replicated))
         return jax.vmap(g, axis_name=self.axis_name)(*sharded)
 
 
@@ -110,10 +108,23 @@ class ShardMapRunner(Runner):
             out *= self.mesh.shape[a]
         return out
 
-    def map(self, fn, sharded, replicated=()):
-        n_shard = len(sharded)
-        spec = P(self.axes if len(self.axes) > 1 else self.axes[0])
+    @property
+    def spec(self) -> P:
+        """Leading (machine) axis split over the machine mesh axes."""
+        return P(self.axes if len(self.axes) > 1 else self.axes[0])
 
+    def shard_blocks(self, X: jax.Array) -> jax.Array:
+        """``Runner.shard_blocks`` placed one block per device, so data
+        blocks sit where their machine's program runs."""
+        return jax.device_put(super().shard_blocks(X),
+                              NamedSharding(self.mesh, self.spec))
+
+    def map(self, fn, sharded, replicated=()):
+        from repro.core.linalg import f32_matmuls
+        n_shard = len(sharded)
+        spec = self.spec
+
+        @f32_matmuls
         def inner(*args):
             blocks = tuple(jax.tree.map(lambda a: a[0], x)
                            for x in args[:n_shard])
@@ -121,8 +132,13 @@ class ShardMapRunner(Runner):
             return jax.tree.map(lambda a: a[None], out)
 
         in_specs = tuple(spec for _ in sharded) + tuple(P() for _ in replicated)
-        return _shard_map(inner, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=spec)(*sharded, *replicated)
+        # per-machine programs are written for vmap semantics: replicated
+        # inputs (support set, hyperparameters) meet block-varying ones in
+        # one op, Pallas kernels included (their output shapes declare no
+        # varying axes), so shard_map's varying-axes typing is off
+        return jax.shard_map(inner, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=spec, check_vma=False)(
+                                 *sharded, *replicated)
 
 
 def pad_blocks(X: jax.Array, M: int) -> tuple[jax.Array, int]:

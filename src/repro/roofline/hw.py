@@ -1,5 +1,5 @@
-"""TPU v5e hardware constants (the TARGET platform of this framework;
-the container executes on CPU, so these feed the analytical roofline)."""
+"""TPU v5e hardware constants (the platform this framework targets), used
+by the analytical roofline of the LM dry-run (``roofline/analysis.py``)."""
 
 PEAK_FLOPS_BF16 = 197e12      # per chip, bf16
 HBM_BW = 819e9                # bytes/s per chip

@@ -11,7 +11,7 @@ The registry closes that gap with the lineage map: a tenant is admitted as
 a (tenant_id, FittedGP, ServeSpec[, StateStore]) tuple; its lineage key is
 
     (method name, ServeSpec.compat_key(kfn), state tree structure,
-     params tree structure)
+     params tree structure, number of devices the state spans)
 
 — exactly the things the compiled executables depend on. Params, state,
 and backend caches are TRACED arguments of every plan executable, so
@@ -114,7 +114,8 @@ def lineage_key(model: api.FittedGP, spec: api.ServeSpec) -> tuple:
     else. Posterior VALUES are absent on purpose: they are traced
     arguments, so equal-key tenants reuse one executable cache."""
     return (model.method.name, spec.compat_key(model.kfn),
-            _tree_struct(model.state), _tree_struct(model.params))
+            _tree_struct(model.state), _tree_struct(model.params),
+            api.state_device_count(model.state))
 
 
 # store type -> the registry method whose plan serves it (fleet re-admission

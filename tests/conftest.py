@@ -10,12 +10,6 @@ import pytest
 
 jax.config.update("jax_enable_x64", True)
 
-# hypothesis is unavailable offline; install the seeded fallback shim before
-# any test module does `from hypothesis import ...` (tests/helpers.py).
-from helpers import install_hypothesis_shim  # noqa: E402
-
-install_hypothesis_shim()
-
 
 @pytest.fixture(scope="session")
 def rng():

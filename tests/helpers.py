@@ -1,77 +1,10 @@
-"""Test helpers: canonical small GP problem generators + a ``hypothesis``
-fallback shim so the suite collects in offline environments."""
+"""Test helpers: canonical small GP problem generators."""
 from __future__ import annotations
-
-import functools
-import inspect
-import random
-import sys
-import types
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import covariance as cov
-
-
-def install_hypothesis_shim() -> None:
-    """Make ``from hypothesis import given, settings, strategies`` work
-    without the real package (unavailable offline).
-
-    The shim replays each property test as a small number of seeded random
-    draws (deterministic across runs — ``random.Random(0)``), which keeps the
-    property tests meaningful where hypothesis is missing while using the
-    real engine whenever it is installed. Called from conftest.py before
-    test modules import.
-    """
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers = lambda min_value=0, max_value=2**16: _Strategy(
-        lambda r: r.randint(min_value, max_value))
-    st.floats = lambda min_value=0.0, max_value=1.0: _Strategy(
-        lambda r: r.uniform(min_value, max_value))
-    st.sampled_from = lambda seq: _Strategy(lambda r: r.choice(list(seq)))
-    st.booleans = lambda: _Strategy(lambda r: bool(r.getrandbits(1)))
-
-    def given(**strategies):
-        def deco(f):
-            @functools.wraps(f)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_shim_max_examples", 10)
-                rng = random.Random(0)
-                for _ in range(n):
-                    drawn = {k: s.draw(rng) for k, s in strategies.items()}
-                    f(*args, **drawn, **kwargs)
-            # hide the drawn parameters from pytest's fixture resolution
-            sig = inspect.signature(f)
-            wrapper.__signature__ = sig.replace(parameters=[
-                p for name, p in sig.parameters.items()
-                if name not in strategies])
-            return wrapper
-        return deco
-
-    def settings(**kwargs):
-        def deco(f):
-            f._shim_max_examples = kwargs.get("max_examples", 10)
-            return f
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = st
-    mod.__shim__ = True
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st
 
 
 def make_problem(*, n=96, u=24, s=12, d=3, M=4, noise=0.3, lengthscale=1.5,

@@ -79,6 +79,20 @@ class TestTheorem2:
         assert block_diag_err(p.cov, blocks) < TOL
 
 
+@pytest.mark.parametrize("method,literal", [
+    (ppitc, pitc.pitc_predict_literal), (ppic, pitc.pic_predict_literal)])
+def test_collective_program_equals_literal(prob, runner, method, literal):
+    """The fully-collective per-machine program (``predict_distributed``:
+    the all-reduce and eqs. 7-8 / 12-14 inside one machine step) equals
+    the centralized literal form, as the cached-state path does."""
+    p = literal(prob["kfn"], prob["params"], prob["S"], prob["X"],
+                prob["y"], prob["U"], prob["M"])
+    q = method.predict_distributed(prob["kfn"], prob["params"], prob["S"],
+                                   prob["X"], prob["y"], prob["U"], runner)
+    np.testing.assert_allclose(q.mean, p.mean, atol=TOL)
+    assert block_diag_err(p.cov, q.blocks) < TOL
+
+
 class TestTheorem3:
     R = 48
 

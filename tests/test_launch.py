@@ -13,11 +13,12 @@ assert len(jax.devices()) == 8
 from repro.configs.registry import smoke_config
 from repro.data.loader import TokenLoader
 from repro.launch import serve as serve_lib, train as train_lib
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 from repro.optim.adam import Adam
 from repro.parallel import sharding as shd
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 
 # --- sharded training: mixtral-family smoke (MoE + FSDP + TP + EP path)
 cfg = smoke_config("mixtral-8x22b").scaled(moe_dispatch="gather")

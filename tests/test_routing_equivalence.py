@@ -18,8 +18,8 @@ map to. The properties locked down here:
 * the routed GPServer resolves every ticket to the routed posterior no
   matter the arrival order.
 
-Runs under real hypothesis when installed, else the seeded shim
-(tests/helpers.py) replays each property as deterministic random draws.
+The properties set ``deadline=None``: each one's first example compiles
+the serving program, and a compile time says nothing about the property.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -56,7 +56,7 @@ def base(prob, state):
 
 
 class TestRoutingInvariance:
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=2**31 - 1))
     def test_permutation_is_bitwise_invariant(self, prob, state, base, seed):
         perm = np.random.RandomState(seed).permutation(prob["U"].shape[0])
@@ -65,7 +65,7 @@ class TestRoutingInvariance:
         np.testing.assert_array_equal(np.asarray(m), np.asarray(base[0])[perm])
         np.testing.assert_array_equal(np.asarray(v), np.asarray(base[1])[perm])
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=2**31 - 1),
            chunk=st.integers(min_value=1, max_value=11))
     def test_rechunking_is_invariant(self, prob, state, base, seed, chunk):
@@ -163,7 +163,7 @@ class TestTwoBucketScatter:
                         prob32["y"], S=prob32["S"],
                         runner=VmapRunner(M=prob32["M"]))
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=2**31 - 1))
     def test_two_bucket_equals_capacity_layout_bitwise_f32(self, prob32,
                                                            state32, seed):
@@ -177,7 +177,7 @@ class TestTwoBucketScatter:
         np.testing.assert_array_equal(np.asarray(m_t), np.asarray(m_c))
         np.testing.assert_array_equal(np.asarray(v_t), np.asarray(v_c))
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=2**31 - 1))
     def test_two_bucket_equals_capacity_layout_f64(self, prob, state, seed):
         perm = np.random.RandomState(seed).permutation(prob["U"].shape[0])
@@ -208,7 +208,7 @@ class TestTwoBucketScatter:
         np.testing.assert_array_equal(np.asarray(m_t), np.asarray(m_c))
         np.testing.assert_array_equal(np.asarray(v_t), np.asarray(v_c))
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=2**31 - 1),
            n=st.integers(min_value=1, max_value=40),
            m=st.integers(min_value=1, max_value=9))
@@ -258,7 +258,7 @@ class TestRegistryAndServer:
         with pytest.raises(ValueError, match="no routed prediction"):
             model.predict_routed_diag(prob["U"])
 
-    @settings(max_examples=5)
+    @settings(max_examples=5, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=2**31 - 1))
     def test_server_resolves_tickets_order_independently(self, prob, state,
                                                          seed):

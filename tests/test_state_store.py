@@ -80,7 +80,7 @@ class TestCholUpdate:
 class TestIncrementalToState:
     def test_assimilate_matches_full_recompute_1e5(self, prob, runner):
         """float64 gate from the issue: after streaming waves through the
-        rank-b update path, (Sdd_L, alpha) match a from-scratch O(|S|^3)
+        rank-b update path, (A_L, w) match a from-scratch O(|S|^3)
         recomputation of the same summaries to 1e-5 (observed ~1e-12)."""
         p = prob
         n1 = p["X"].shape[0] // 2
@@ -90,15 +90,15 @@ class TestIncrementalToState:
         # full recompute of the SAME summaries (alive-mask refold)
         ref = online.with_alive(store.store, store.store.alive,
                                 mode="refold")
-        np.testing.assert_allclose(store.store.Sdd_L, ref.Sdd_L, atol=1e-5)
+        np.testing.assert_allclose(store.store.A_L, ref.A_L, atol=1e-5)
         st_inc = store.to_state()
         st_ref = online.to_state(ref, p["S"])
-        np.testing.assert_allclose(st_inc.alpha, st_ref.alpha, atol=1e-5)
+        np.testing.assert_allclose(st_inc.w, st_ref.w, atol=1e-5)
         # and both match a genuinely cold fit of the concatenated data
         cold = ppitc.fit(p["kfn"], p["params"], p["X"], p["y"], S=p["S"],
                          runner=VmapRunner(M=2 * p["M"]))
-        np.testing.assert_allclose(st_inc.Sdd_L, cold.Sdd_L, atol=1e-5)
-        np.testing.assert_allclose(st_inc.alpha, cold.alpha, atol=1e-5)
+        np.testing.assert_allclose(st_inc.A_L, cold.A_L, atol=1e-5)
+        np.testing.assert_allclose(st_inc.w, cold.w, atol=1e-5)
 
     def test_retire_downdate_matches_survivor_refold(self, prob, runner):
         p = prob
@@ -106,19 +106,19 @@ class TestIncrementalToState:
                                p["y"], S=p["S"], runner=runner).retire(1)
         ref = online.with_alive(store.store, store.store.alive,
                                 mode="refold")
-        np.testing.assert_allclose(store.store.Sdd_L, ref.Sdd_L, atol=1e-5)
+        np.testing.assert_allclose(store.store.A_L, ref.A_L, atol=1e-5)
 
     def test_to_state_has_no_cubic_refactorization(self, prob, runner):
         """Structural check of the O(|S|^2) claim: to_state after retire
         reuses the cached (downdated) factor — it does NOT equal a chol of
         the alive Sdd bit-for-bit, it equals the downdate of the cold
-        factor (same matrix, different float path)."""
+        (whitened) factor (same matrix, different float path)."""
         p = prob
         store = api.init_store("ppitc", p["kfn"], p["params"], p["X"],
                                p["y"], S=p["S"], runner=runner)
-        expected = linalg.chol_update_rank(store.store.Sdd_L,
-                                           store.store.F[2], sign=-1.0)
-        np.testing.assert_array_equal(store.retire(2).to_state().Sdd_L,
+        expected = linalg.chol_update_rank(store.store.A_L,
+                                           store.store.G[2], sign=-1.0)
+        np.testing.assert_array_equal(store.retire(2).to_state().A_L,
                                       expected)
 
 
@@ -150,8 +150,8 @@ class TestWithAliveHamming:
         mask = np.asarray(store.alive).copy()
         mask[1] = False
         flipped = store.with_alive(jnp.asarray(mask))
-        np.testing.assert_array_equal(flipped.store.Sdd_L,
-                                      store.retire(1).store.Sdd_L)
+        np.testing.assert_array_equal(flipped.store.A_L,
+                                      store.retire(1).store.A_L)
 
     def test_incremental_matches_refold(self, prob, runner):
         store = self._store(prob, runner)
@@ -162,7 +162,7 @@ class TestWithAliveHamming:
         ref = online.with_alive(store.store, jnp.asarray(mask),
                                 mode="refold")
         np.testing.assert_array_equal(inc.alive, ref.alive)
-        np.testing.assert_allclose(inc.Sdd_L, ref.Sdd_L, atol=1e-10)
+        np.testing.assert_allclose(inc.A_L, ref.A_L, atol=1e-10)
         np.testing.assert_allclose(inc.ydd, ref.ydd, atol=1e-10)
 
     def test_wholesale_flip_refolds(self, prob, runner):
@@ -174,12 +174,12 @@ class TestWithAliveHamming:
         auto = online.with_alive(store.store, jnp.asarray(mask))
         ref = online.with_alive(store.store, jnp.asarray(mask),
                                 mode="refold")
-        np.testing.assert_array_equal(auto.Sdd_L, ref.Sdd_L)
+        np.testing.assert_array_equal(auto.A_L, ref.A_L)
 
     def test_noop_mask_returns_store_unchanged(self, prob, runner):
         store = self._store(prob, runner)
         same = online.with_alive(store.store, store.store.alive)
-        np.testing.assert_array_equal(same.Sdd_L, store.store.Sdd_L)
+        np.testing.assert_array_equal(same.A_L, store.store.A_L)
 
     def test_bad_mode_rejected(self, prob, runner):
         store = self._store(prob, runner)
@@ -322,7 +322,7 @@ class TestStoreLifecycle:
         grown = store.assimilate(X2, y2, runner=VmapRunner(M=2))   # b=3
         ref = online.with_alive(grown.store, grown.store.alive,
                                 mode="refold")
-        np.testing.assert_allclose(grown.store.Sdd_L, ref.Sdd_L, atol=1e-10)
+        np.testing.assert_allclose(grown.store.A_L, ref.A_L, atol=1e-10)
 
 
 # ---------------------------------------------------------------------------
@@ -541,10 +541,67 @@ class TestSerialize:
                           S=prob["S"], runner=runner)
         path = serialize.save_state(tmp_path / "s.npz", state)
         with np.load(path) as z:
-            payload = {k: z[k] for k in z.files if k != "field:alpha"}
+            payload = {k: z[k] for k in z.files if k != "field:w"}
         np.savez(open(path, "wb"), **payload)
         with pytest.raises(ValueError, match="field mismatch"):
             serialize.load_state(path)
+
+    @pytest.mark.parametrize("name", ["FGPState", "PITCState", "PICState",
+                                      "PICFState"])
+    def test_v1_state_checkpoint_loads(self, prob, runner, tmp_path, name):
+        """Schema-v1 checkpoints still load: the pPITC/pPIC layouts (an
+        unwhitened Sdd_L and alpha = Sdd⁻¹ÿ; pPIC's Ksd/ydot/beta/B/Sdot
+        block caches) convert to the whitened v2 state, the unchanged
+        types load as they are."""
+        state = self._states(prob, runner)[name]
+        fields = {f: np.asarray(v) for f, v in zip(state._fields, state)}
+        if name in ("PITCState", "PICState"):
+            L, A_L = fields.pop("Kss_L"), fields.pop("A_L")
+            fields["Kss_L"], fields["Sdd_L"] = L, L @ A_L
+            fields["alpha"] = np.linalg.solve(
+                (L @ A_L).T, fields.pop("w"))       # Sdd⁻¹ÿ = L⁻ᵀA_L⁻ᵀw
+        if name == "PICState":
+            V = fields.pop("V")
+            M, s_, _ = V.shape
+            fields["Ksd"] = np.einsum("st,mtb->msb", L, V)
+            fields.update(ydot=np.zeros((M, s_)), beta=np.zeros((M, s_)),
+                          B=np.zeros((M, s_, s_)), Sdot=np.zeros((M, s_, s_)))
+        path = tmp_path / f"{name}_v1.npz"
+        with open(path, "wb") as fh:
+            np.savez(fh, __schema__=np.int64(1), __state__=np.str_(name),
+                     **{"field:" + f: v for f, v in fields.items()})
+        loaded = serialize.load_state(path)
+        assert type(loaded) is type(state)
+        for f, a, b in zip(state._fields, state, loaded):
+            np.testing.assert_allclose(b, a, atol=1e-9, err_msg=f)
+
+    @pytest.mark.parametrize("method", ["ppitc", "ppic"])
+    def test_v1_store_checkpoint_loads(self, prob, runner, tmp_path, method):
+        """Schema-v1 store checkpoints (unwhitened F and Sdd_L; pPIC's
+        Ksd/beta/B block caches) convert to the whitened v2 store and keep
+        serving the same posterior."""
+        p = prob
+        store = api.init_store(method, p["kfn"], p["params"], p["X"],
+                               p["y"], S=p["S"], runner=runner)
+        path = serialize.save_store(tmp_path / "v2.npz", store)
+        with np.load(path) as z:
+            a = {k: z[k] for k in z.files if k != "__checksums__"}
+        L = a["sum:Kss_L"]
+        a["sum:F"] = np.einsum("st,mtb->msb", L, a.pop("sum:G"))
+        a["sum:Sdd_L"] = L @ a.pop("sum:A_L")
+        if method == "ppic":
+            V = a.pop("blk:V")
+            a["blk:Ksd"] = np.einsum("st,mtb->msb", L, V)
+            a["blk:beta"] = np.zeros(V.shape[:2])
+            a["blk:B"] = np.zeros(V.shape[:2] + V.shape[1:2])
+        a["__store_schema__"] = np.int64(1)
+        v1 = tmp_path / "v1.npz"
+        with open(v1, "wb") as fh:
+            np.savez(fh, **a)
+        loaded = serialize.load_store(v1)
+        for x, y in zip(jax.tree.leaves(store.to_state()),
+                        jax.tree.leaves(loaded.to_state())):
+            np.testing.assert_allclose(y, x, atol=1e-9)
 
     def test_server_checkpoint_swap(self, prob, runner, tmp_path):
         """Replica flow: server A checkpoints, server B (fitted on a
