@@ -62,6 +62,7 @@ import numpy as np
 
 from repro.core import covariance, linalg
 from repro.parallel.runner import ROUTED_ALPHA
+from repro.runtime import spans
 
 
 # ---------------------------------------------------------------------------
@@ -501,7 +502,9 @@ class ServePlan:
         """One jitted executable per key, created lazily, shared across
         rebinds. ``build`` returns the python callable to jit; a trace
         counter rides inside it so the lifecycle tests can assert the
-        zero-recompile hot-swap contract."""
+        zero-recompile hot-swap contract. The program is named after its
+        key (``jit_serve_diag``, ``jit_serve_routed_g2``), so the entry
+        points stay apart in a profiler trace."""
         fn = self._exec.get(key)
         if fn is None:
             inner = linalg.f32_matmuls(build())
@@ -511,6 +514,8 @@ class ServePlan:
                 stats.n_traces += 1
                 return inner(*args)
 
+            counted.__name__ = counted.__qualname__ = "serve_" + (
+                key if isinstance(key, str) else f"{key[0]}_g{key[1]}")
             fn = self._exec[key] = jax.jit(counted)
         return fn
 
@@ -530,11 +535,14 @@ class ServePlan:
 
     def diag(self, U) -> tuple[jax.Array, jax.Array]:
         """(mean, var) over a (u, d) batch — THE serving hot path."""
-        Up, u = self._padded(U)
-        mean, var = self._diag_exec()(self.params, self.state, self.caches,
-                                      Up)
+        with spans.span("plan.pad"):
+            Up, u = self._padded(U)
+        with spans.span("plan.exec"):
+            mean, var = self._diag_exec()(self.params, self.state,
+                                          self.caches, Up)
         self.stats.n_diag_batches += 1
-        return mean[:u], var[:u]
+        with spans.span("plan.trim"):
+            return mean[:u], var[:u]
 
     def routed_diag(self, U, block_alive=None):
         """Generic routed path: the method's raw routed impl, jitted with
@@ -557,14 +565,17 @@ class ServePlan:
                 f"method {self.method.name!r} has no routed serving "
                 f"program; its posterior does not depend on query-block "
                 f"assignment — use plan.diag")
-        Up, u = self._padded(U)
+        with spans.span("plan.pad"):
+            Up, u = self._padded(U)
         fn = self._jitted(
             "routed", lambda: lambda params, state, caches, U:
                 impl(kfn, params, state, U, tile=tile))
-        mean, var = fn(self.params, self.state, self.caches, Up)
+        with spans.span("plan.exec"):
+            mean, var = fn(self.params, self.state, self.caches, Up)
         self.stats.n_routed_batches += 1
         self.stats.last_g = None
-        return mean[:u], var[:u]
+        with spans.span("plan.trim"):
+            return mean[:u], var[:u]
 
     def full(self, U):
         """The method's native posterior (mean + covariance view). Queries

@@ -49,6 +49,7 @@ from repro.core import api, clustering, linalg
 from repro.core.ppitc import (GlobalSummary, LocalSummary, local_summary,
                               predict_batch, whitened_factor)
 from repro.parallel.runner import Runner
+from repro.runtime import spans
 
 
 class SummaryStore(NamedTuple):
@@ -92,7 +93,8 @@ def _summarize(kfn, params, S, X, y, runner: Runner):
         loc, (_, V, C_L, _) = local_summary(kfn, params, S, Kss_L, Xm, ym)
         return loc, whitened_factor(V, C_L)
 
-    return runner.map(fn, (Xb, yb), (params, S))
+    with spans.span("online.summarize"):
+        return runner.map(fn, (Xb, yb), (params, S))
 
 
 def _pad_factor(G: jax.Array, b: int) -> jax.Array:
@@ -111,11 +113,12 @@ def _cold_store(kfn, params, S, locals_: LocalSummary,
     place the global factor is Cholesky'd from scratch (cold O(|S|³), paid
     once per store lifetime) — shared by the PITC and PIC builders so both
     anchor the same jitter to the same matrix."""
-    alive = jnp.ones((locals_.ydot.shape[0],), bool)
-    Kss = kfn(params, S, S)
-    ydd = jnp.sum(locals_.ydot, axis=0)
-    return SummaryStore(locals_, G, alive, Kss, linalg.chol(Kss),
-                        _whitened_chol(G), ydd)
+    with spans.span("online.cold_store"):
+        alive = jnp.ones((locals_.ydot.shape[0],), bool)
+        Kss = kfn(params, S, S)
+        ydd = jnp.sum(locals_.ydot, axis=0)
+        return SummaryStore(locals_, G, alive, Kss, linalg.chol(Kss),
+                            _whitened_chol(G), ydd)
 
 
 def build(kfn, params, S, X, y, runner: Runner) -> SummaryStore:
@@ -142,26 +145,30 @@ def to_state(store: SummaryStore, S: jax.Array) -> api.PITCState:
     only the two triangular solves of the whitened weights
     w = A_L⁻¹ Kss_L⁻¹ ÿ remain here — the |S|³ factorization happens once
     at ``build`` and never again across the store's lifetime."""
-    c = linalg.tri_solve(store.Kss_L, store.ydd)
-    return api.PITCState(S, store.Kss_L, store.A_L,
-                         linalg.tri_solve(store.A_L, c))
+    with spans.span("online.to_state"):
+        c = linalg.tri_solve(store.Kss_L, store.ydd)
+        return api.PITCState(S, store.Kss_L, store.A_L,
+                             linalg.tri_solve(store.A_L, c))
 
 
 def _fold_in(store: SummaryStore, locals_new: LocalSummary,
              G_new: jax.Array) -> SummaryStore:
     """Append new machine blocks and rank-update the cached global factors."""
-    b = max(store.G.shape[-1], G_new.shape[-1])
-    merged = LocalSummary(
-        jnp.concatenate([store.locals_.ydot, locals_new.ydot]),
-        jnp.concatenate([store.locals_.Sdot, locals_new.Sdot]))
-    G = jnp.concatenate([_pad_factor(store.G, b), _pad_factor(G_new, b)])
-    alive = jnp.concatenate(
-        [store.alive, jnp.ones((G_new.shape[0],), bool)])
-    # one rank-(M'·b) update: stack the new machines' factor columns
-    W = jnp.concatenate([g for g in G_new], axis=1)        # (s, M'·b)
-    A_L = linalg.chol_update_rank(store.A_L, W)
-    ydd = store.ydd + jnp.sum(locals_new.ydot, axis=0)
-    return SummaryStore(merged, G, alive, store.Kss, store.Kss_L, A_L, ydd)
+    with spans.span("online.fold_in"):
+        b = max(store.G.shape[-1], G_new.shape[-1])
+        merged = LocalSummary(
+            jnp.concatenate([store.locals_.ydot, locals_new.ydot]),
+            jnp.concatenate([store.locals_.Sdot, locals_new.Sdot]))
+        G = jnp.concatenate([_pad_factor(store.G, b), _pad_factor(G_new, b)])
+        alive = jnp.concatenate(
+            [store.alive, jnp.ones((G_new.shape[0],), bool)])
+        # one rank-(M'·b) update: stack the new machines' factor columns
+        W = jnp.concatenate([g for g in G_new], axis=1)        # (s, M'·b)
+        with spans.span("online.rank_update"):
+            A_L = linalg.chol_update_rank(store.A_L, W)
+        ydd = store.ydd + jnp.sum(locals_new.ydot, axis=0)
+        return SummaryStore(merged, G, alive, store.Kss, store.Kss_L, A_L,
+                            ydd)
 
 
 def assimilate(store: SummaryStore, kfn, params, S, X_new, y_new,
@@ -172,8 +179,9 @@ def assimilate(store: SummaryStore, kfn, params, S, X_new, y_new,
     are reused untouched, and the global factor is advanced by one
     rank-(M'·b) Cholesky update — O(|S|²·b) per new block, no |S|³
     anywhere."""
-    locals_new, G_new = _summarize(kfn, params, S, X_new, y_new, runner)
-    return _fold_in(store, locals_new, G_new)
+    with spans.span("online.assimilate"):
+        locals_new, G_new = _summarize(kfn, params, S, X_new, y_new, runner)
+        return _fold_in(store, locals_new, G_new)
 
 
 def retire(store: SummaryStore, machine: int) -> SummaryStore:

@@ -49,6 +49,7 @@ from repro.parallel.runner import (ROUTED_ALPHA, Runner, gather_by_block,
                                    gather_two_bucket, pad_blocks,
                                    routed_capacity, scatter_by_block,
                                    scatter_two_bucket)
+from repro.runtime import spans
 
 
 def machine_step(kfn, params, S, Xm, ym, Um, *, axis_name):
@@ -491,8 +492,10 @@ class PICServePlan(api.ServePlan):
                 "and pad-packing pick data-dependent programs) and cannot "
                 "run under jit/vmap; call it with concrete batches, or "
                 "use plan.diag for the traceable unrouted path")
-        Up, u = self._padded(U)
-        assign, g = self._route(np.asarray(Up), u)
+        with spans.span("plan.pad"):
+            Up, u = self._padded(U)
+        with spans.span("plan.route"):
+            assign, g = self._route(np.asarray(Up), u)
         self.stats.last_degraded = None
         dead = None
         if block_alive is not None:
@@ -503,20 +506,21 @@ class PICServePlan(api.ServePlan):
                     f"block_alive must be an ({M},) bool mask over the "
                     f"state's blocks; got shape {alive.shape}")
             dead = ~alive[assign]
-        if dead is not None and dead.any():
-            mean, var = self._routed_deg_exec(g)(self.params, self.state,
-                                                 self.caches, Up, assign,
-                                                 dead)
-            self.stats.last_degraded = dead[:u].copy()
-            self.stats.n_degraded_rows += int(dead[:u].sum())
-        else:
-            mean, var = self._routed_exec(g)(self.params, self.state,
-                                             self.caches, Up, assign)
+        with spans.span("plan.exec"):
+            if dead is not None and dead.any():
+                mean, var = self._routed_deg_exec(g)(
+                    self.params, self.state, self.caches, Up, assign, dead)
+                self.stats.last_degraded = dead[:u].copy()
+                self.stats.n_degraded_rows += int(dead[:u].sum())
+            else:
+                mean, var = self._routed_exec(g)(self.params, self.state,
+                                                 self.caches, Up, assign)
         self.stats.n_routed_batches += 1
         self.stats.last_g = g
         if g == 0:
             self.stats.n_g0_batches += 1
-        return mean[:u], var[:u]
+        with spans.span("plan.trim"):
+            return mean[:u], var[:u]
 
     def _route(self, Up: np.ndarray, u: int) -> tuple[np.ndarray, int]:
         """(assign, g) for a staged padded batch whose first ``u`` rows are

@@ -42,6 +42,7 @@ from repro.core import covariance as cov
 from repro.core import linalg
 from repro.core.gp import GPPosterior
 from repro.parallel.runner import Runner
+from repro.runtime import spans
 
 
 class LocalSummary(NamedTuple):
@@ -143,7 +144,8 @@ def fit(kfn, params, X, y, *, S, runner: Runner) -> api.PITCState:
     share one code path.
     """
     from repro.core import online
-    return online.to_state(online.build(kfn, params, S, X, y, runner), S)
+    with spans.span("fit.ppitc"):
+        return online.to_state(online.build(kfn, params, S, X, y, runner), S)
 
 
 @linalg.f32_matmuls

@@ -56,6 +56,7 @@ import jax
 import numpy as np
 
 from repro.core import clustering
+from repro.runtime import spans
 from repro.serving.registry import Tenant, TenantRegistry
 from repro.serving.stats import rollup
 
@@ -121,26 +122,30 @@ class TenantScheduler:
         runs BEFORE enqueue; size/deadline triggers after, exactly as in
         ``GPServer.submit``."""
         t = self.registry.get(tenant_id)
-        now = self._clock()
-        if t.max_pending is not None and len(t.queue) >= t.max_pending:
-            if t.overflow == "reject":
-                t.stats.n_rejected += 1
-                raise AdmissionError(
-                    f"tenant {tenant_id!r}: queue depth {len(t.queue)} at "
-                    f"max_pending={t.max_pending} (reject policy); pump or "
-                    f"flush before resubmitting")
-            t.queue.pop(0)
-            t.stats.n_shed += 1
-        t.stats.observe_arrival(now, t.last_arrival)
-        t.last_arrival = now
-        ticket = t.next_ticket
-        t.next_ticket += 1
-        t.queue.append((ticket, np.asarray(x), now))
-        if len(t.queue) >= t.max_batch:
-            self._flush(t, "size")
-        elif self._past_deadline(t, now):
-            self._flush(t, "deadline")
-        return ticket
+        # a rejected submit takes no ticket: its record carries the ticket
+        # of the next admitted submit, and is recorded before it
+        with spans.span("sched.submit", tenant=tenant_id,
+                        ticket=t.next_ticket):
+            now = self._clock()
+            if t.max_pending is not None and len(t.queue) >= t.max_pending:
+                if t.overflow == "reject":
+                    t.stats.n_rejected += 1
+                    raise AdmissionError(
+                        f"tenant {tenant_id!r}: queue depth {len(t.queue)} at "
+                        f"max_pending={t.max_pending} (reject policy); pump or "
+                        f"flush before resubmitting")
+                t.queue.pop(0)
+                t.stats.n_shed += 1
+            t.stats.observe_arrival(now, t.last_arrival)
+            t.last_arrival = now
+            ticket = t.next_ticket
+            t.next_ticket += 1
+            t.queue.append((ticket, np.asarray(x), now))
+            if len(t.queue) >= t.max_batch:
+                self._flush(t, "size")
+            elif self._past_deadline(t, now):
+                self._flush(t, "deadline")
+            return ticket
 
     def pending(self, tenant_id: str) -> int:
         return self.registry.get(tenant_id).pending
@@ -186,18 +191,19 @@ class TenantScheduler:
         passed, earliest-weighted-deadline first (admission order breaks
         ties deterministically). Call from the serving loop whenever idle.
         Returns total tickets resolved (0 if nothing was due)."""
-        now = self._clock()
-        due = []
-        for t in self.registry.tenants():
-            if (t.health is not None and t.health.dead_blocks()
-                    and t.health.policy.checkpoint is not None
-                    and now >= t.health.revive_due):
-                self._try_revive(t, now)
-            d = self._due_at(t)
-            if d is not None and now >= d:
-                due.append((d, t.seq, t))
-        due.sort(key=lambda e: (e[0], e[1]))
-        return sum(self._flush(t, "deadline") for _, _, t in due)
+        with spans.span("sched.pump"):
+            now = self._clock()
+            due = []
+            for t in self.registry.tenants():
+                if (t.health is not None and t.health.dead_blocks()
+                        and t.health.policy.checkpoint is not None
+                        and now >= t.health.revive_due):
+                    self._try_revive(t, now)
+                d = self._due_at(t)
+                if d is not None and now >= d:
+                    due.append((d, t.seq, t))
+            due.sort(key=lambda e: (e[0], e[1]))
+            return sum(self._flush(t, "deadline") for _, _, t in due)
 
     def _try_revive(self, t: Tenant, now: float) -> bool:
         """Background revive: reload the tenant's last known-good
@@ -243,24 +249,31 @@ class TenantScheduler:
         if not t.queue:
             return 0
         queue = t.queue
-        U = np.stack([x for _, x, _ in queue])
-        tickets = [tk for tk, _, _ in queue]
-        # predict before clearing: a failing batch (e.g. one malformed
-        # point) must not destroy the other pending tickets
-        mean, var, deg = self._dispatch(t, U)
-        now = self._clock()
-        for _, _, t_sub in queue:
-            t.stats.staleness.record((now - t_sub) * 1e3)
-        t.stats.observe_flush(
-            trigger, t.plan.stats.last_g if t.spec.routed else None)
-        if deg is not None and deg.any():
-            t.stats.n_degraded_flushes += 1
-            t.stats.n_degraded_rows += int(deg.sum())
-        t.queue.clear()
-        self.dispatch_log.append((t.tenant_id, trigger, len(tickets)))
-        for i, tk in enumerate(tickets):
-            t.ready[tk] = (mean[i], var[i])
-            t.ready_degraded[tk] = bool(deg[i]) if deg is not None else False
+        # the queue holds consecutive tickets: shedding drops its oldest
+        with spans.span("sched.flush", tenant=t.tenant_id, trigger=trigger,
+                        first_ticket=queue[0][0], n_tickets=len(queue)):
+            now = self._clock()      # staleness: submit -> this dispatch
+            with spans.span("sched.stack"):
+                U = np.stack([x for _, x, _ in queue])
+                tickets = [tk for tk, _, _ in queue]
+            # predict before clearing: a failing batch (e.g. one malformed
+            # point) must not destroy the other pending tickets
+            with spans.span("sched.dispatch"):
+                mean, var, deg = self._dispatch(t, U)
+            for _, _, t_sub in queue:
+                t.stats.staleness.record((now - t_sub) * 1e3)
+            t.stats.observe_flush(
+                trigger, t.plan.stats.last_g if t.spec.routed else None)
+            if deg is not None and deg.any():
+                t.stats.n_degraded_flushes += 1
+                t.stats.n_degraded_rows += int(deg.sum())
+            t.queue.clear()
+            self.dispatch_log.append((t.tenant_id, trigger, len(tickets)))
+            with spans.span("sched.unpack"):
+                for i, tk in enumerate(tickets):
+                    t.ready[tk] = (mean[i], var[i])
+                    t.ready_degraded[tk] = (bool(deg[i]) if deg is not None
+                                            else False)
         # bound memory against abandoned tickets: evict oldest results
         # (dicts preserve insertion order) beyond max_ready
         while len(t.ready) > t.max_ready:
@@ -287,16 +300,18 @@ class TenantScheduler:
         ticket is still pending. The only point this layer blocks on the
         device."""
         t = self.registry.get(tenant_id)
-        if ticket not in t.ready:
-            self._flush(t, "manual")
-        try:
-            out = t.ready.pop(ticket)
-        except KeyError:
-            raise KeyError(
-                f"ticket {ticket}: unknown, already collected, shed, or "
-                f"evicted (max_ready={t.max_ready})") from None
-        t.ready_degraded.pop(ticket, None)
-        return jax.block_until_ready(out)
+        with spans.span("sched.result", tenant=tenant_id, ticket=ticket):
+            if ticket not in t.ready:
+                self._flush(t, "manual")
+            try:
+                out = t.ready.pop(ticket)
+            except KeyError:
+                raise KeyError(
+                    f"ticket {ticket}: unknown, already collected, shed, or "
+                    f"evicted (max_ready={t.max_ready})") from None
+            t.ready_degraded.pop(ticket, None)
+            with spans.span("sched.wait"):
+                return jax.block_until_ready(out)
 
     def collect(self, tenant_id: str, ticket: int):
         """(mean, var, degraded) for a tenant's ticket — ``result`` plus
@@ -446,10 +461,12 @@ class TenantScheduler:
         store is reassigned, so a rejected state leaves the tenant on the
         old store AND the old posterior."""
         t = self.registry.get(tenant_id)
-        self._flush(t, "manual")
-        self.registry.rebind(tenant_id, store.to_state())
-        t.store = store
-        t.stats.n_updates += 1
+        with spans.span("sched.commit_store", tenant=tenant_id):
+            self._flush(t, "manual")
+            with spans.span("sched.rebind"):
+                self.registry.rebind(tenant_id, store.to_state())
+            t.store = store
+            t.stats.n_updates += 1
 
     # -- observability -------------------------------------------------------
 

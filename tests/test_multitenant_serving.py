@@ -436,6 +436,28 @@ class TestStatsAndRollup:
         assert snap["staleness_ms"]["p99"] >= snap["staleness_ms"]["p50"]
         assert snap["interarrival_ms"] == pytest.approx(1.0)
 
+    def test_staleness_ends_where_the_flush_is_dispatched(self, prob, models,
+                                                         monkeypatch):
+        """Staleness is submit -> flush dispatch: the time the dispatch
+        itself takes is not queue time."""
+        clk = [0.0]
+        sched = _sched(lambda: clk[0])
+        sched.admit("a", models[0], api.ServeSpec(max_batch=8))
+        real = sched._dispatch
+
+        def slow(t, U):
+            clk[0] += 0.5            # the dispatch takes 500 ms
+            return real(t, U)
+
+        monkeypatch.setattr(sched, "_dispatch", slow)
+        sched.submit("a", prob["U"][0])
+        clk[0] = 0.002
+        sched.submit("a", prob["U"][1])
+        clk[0] = 0.010
+        sched.flush("a")
+        samples = sorted(sched.stats("a").staleness._buf)
+        assert samples == pytest.approx([8.0, 10.0])
+
     def test_gpserver_stats_is_serving_stats(self, prob, models):
         """GPServer re-exports ServeStats from serving/ — one stats schema
         for single- and multi-tenant serving."""
