@@ -19,10 +19,10 @@ architecture):
   (oldest pending ticket exceeds ``flush_deadline_ms`` at the next
   ``pump()``), so p99 latency at low arrival rates is bounded by the
   deadline instead of by how long the queue takes to fill;
-* flushes dispatch asynchronously: the jitted predict and the per-ticket
-  slices are enqueued on the XLA stream and nothing blocks until a ticket
-  is actually resolved (``result`` calls ``block_until_ready``), so compute
-  overlaps with further submits;
+* flushes dispatch asynchronously: the jitted predict and one host copy of
+  its answers are enqueued on the XLA stream and nothing blocks until a
+  ticket is actually resolved (the first ``result`` of a flush waits for
+  the copy), so compute overlaps with further submits;
 * with ``routed=True`` (pPIC/PIC states carrying block centroids) the plan
   routes each flush's staged batch host-side once; that single assignment
   both selects the matching overflow program — balanced flushes run the
@@ -221,10 +221,10 @@ class GPServer:
     def flush(self, *, trigger: str = "manual") -> int:
         """Serve the queue with one padded, jitted plan dispatch.
 
-        Dispatch is asynchronous: the predict call and the per-ticket result
-        slices go onto the XLA stream without blocking; the host returns to
-        accepting submits immediately and each ticket materializes at
-        ``result`` time. Returns the number of tickets resolved.
+        Dispatch is asynchronous: the predict call and one host copy of its
+        answers go onto the XLA stream without blocking; the host returns to
+        accepting submits immediately and the flush's first ``result`` waits
+        for the copy. Returns the number of tickets resolved.
         """
         return self._sched.flush(self._TENANT, trigger=trigger)
 
@@ -239,15 +239,17 @@ class GPServer:
         """Block until every already-flushed result has materialized.
 
         A measurement/shutdown barrier (benchmarks use it to charge real
-        flush compute to the clock); normal serving lets ``result`` block
-        per ticket instead."""
+        flush compute to the clock); normal serving lets the first
+        ``result`` of each flush block instead."""
         self._sched.sync(self._TENANT)
 
-    def result(self, ticket: int) -> tuple[jax.Array, jax.Array]:
-        """(mean, var) for a ticket; flushes if it is still queued.
+    def result(self, ticket: int) -> tuple[Any, Any]:
+        """(mean, var) for a ticket, as host NumPy scalars; flushes if it is
+        still queued.
 
-        This is the only point the serving layer blocks on the device —
-        everything upstream (flushes, slices) was dispatched asynchronously.
+        This is the only point the serving layer blocks on the device (the
+        first ``result`` of a flush waits for its host copy) — everything
+        upstream was dispatched asynchronously.
         """
         return self._sched.result(self._TENANT, ticket)
 

@@ -35,8 +35,10 @@ from typing import NamedTuple
 import jax
 from jax.profiler import TraceAnnotation
 
-# more than a 30-s closed serving window at ~400 queries/s writes
-CAPACITY = 1 << 17
+# a closed serving window writes ~3.1 records a query (submit, result, wait,
+# and a 64-row flush's seven): this holds 30 s at ~45,000 queries/s, in
+# ~1.5 GB of host memory at ~370 bytes a record
+CAPACITY = 1 << 22
 COMPILE = "compile"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

@@ -76,10 +76,10 @@ class TenantScheduler:
 
     The request path mirrors ``GPServer`` per tenant — ``submit`` returns a
     ticket (per-tenant namespace, starting at 0), size/deadline/manual
-    triggers drain the queue through one padded plan dispatch, ``result``
-    blocks on exactly one ticket — plus the cross-tenant policies described
-    in the module docstring. ``GPServer`` itself is a one-tenant client of
-    this class.
+    triggers drain the queue through one padded plan dispatch and start one
+    host copy of its answers, and the first ``result`` of a flush waits for
+    that copy — plus the cross-tenant policies described in the module
+    docstring. ``GPServer`` itself is a one-tenant client of this class.
     """
 
     def __init__(self, registry: TenantRegistry | None = None, *,
@@ -269,9 +269,14 @@ class TenantScheduler:
                 t.stats.n_degraded_rows += int(deg.sum())
             t.queue.clear()
             self.dispatch_log.append((t.tenant_id, trigger, len(tickets)))
+            # one host copy per flush; each ticket keeps the flush's arrays
+            # and its row, so no device program runs per ticket
             with spans.span("sched.unpack"):
+                for a in (mean, var):
+                    if isinstance(a, jax.Array):   # health returns numpy
+                        a.copy_to_host_async()
                 for i, tk in enumerate(tickets):
-                    t.ready[tk] = (mean[i], var[i])
+                    t.ready[tk] = (mean, var, i)
                     t.ready_degraded[tk] = (bool(deg[i]) if deg is not None
                                             else False)
         # bound memory against abandoned tickets: evict oldest results
@@ -284,39 +289,47 @@ class TenantScheduler:
         return len(tickets)
 
     def done(self, tenant_id: str, ticket: int) -> bool:
-        """True when a ticket's flush was dispatched (device values may
-        still be in flight; ``result``/``sync`` do the blocking)."""
+        """True when a ticket's flush was dispatched and its answer is not
+        yet collected. Never blocks: the flush's values and their host copy
+        may still be in flight; ``result``/``sync`` do the blocking."""
         return ticket in self.registry.get(tenant_id).ready
 
     def sync(self, tenant_id: str | None = None) -> None:
-        """Block until every already-flushed result (of one tenant, or of
-        all) has materialized — a measurement/shutdown barrier."""
+        """Block until the device has computed every flush that holds an
+        uncollected ticket (of one tenant, or of all) — a
+        measurement/shutdown barrier. Returns nothing; ``result`` then
+        reads each answer from the flush's host copy."""
         tenants = (self.registry.tenants() if tenant_id is None
                    else [self.registry.get(tenant_id)])
         jax.block_until_ready([list(t.ready.values()) for t in tenants])
 
     def result(self, tenant_id: str, ticket: int):
-        """(mean, var) for a tenant's ticket; flushes its queue if the
-        ticket is still pending. The only point this layer blocks on the
-        device."""
+        """(mean, var) for a tenant's ticket: host NumPy scalars of the
+        plan's output dtype (``float32`` for a float32 deployment), bitwise
+        the ticket's row of its flush's output. Flushes the tenant's queue
+        if the ticket is still pending. The only point this layer blocks on
+        the device: the first ``result`` of a flush waits for the flush's
+        one host copy, which JAX keeps on the flush's arrays, so the
+        flush's other tickets read host memory."""
         t = self.registry.get(tenant_id)
         with spans.span("sched.result", tenant=tenant_id, ticket=ticket):
             if ticket not in t.ready:
                 self._flush(t, "manual")
             try:
-                out = t.ready.pop(ticket)
+                mean, var, i = t.ready.pop(ticket)
             except KeyError:
                 raise KeyError(
                     f"ticket {ticket}: unknown, already collected, shed, or "
                     f"evicted (max_ready={t.max_ready})") from None
             t.ready_degraded.pop(ticket, None)
             with spans.span("sched.wait"):
-                return jax.block_until_ready(out)
+                return np.asarray(mean)[i], np.asarray(var)[i]
 
     def collect(self, tenant_id: str, ticket: int):
-        """(mean, var, degraded) for a tenant's ticket — ``result`` plus
-        the per-query degradation flag: True when the row's routed block
-        was health-retired and the answer came from the global S-space
+        """(mean, var, degraded) for a tenant's ticket — ``result`` (host
+        NumPy scalars; blocks where ``result`` does) plus the per-query
+        degradation flag, a ``bool``: True when the row's routed block was
+        health-retired and the answer came from the global S-space
         posterior (bounded accuracy loss, see serving/health.py). Callers
         that ignore the flag can keep using ``result``."""
         t = self.registry.get(tenant_id)
