@@ -3,7 +3,7 @@
 The configuration file (``bench/configs/<name>.json``) states the method,
 the data scale (|D|, M, |S|), the kernel path and the serving policy; this
 module turns it into the calls a user of the program makes: ``api.fit`` /
-``api.init_store`` over a ``VmapRunner``, a ``ServeSpec``, and a tenant of a
+``api.init_store`` over the cell's runner, a ``ServeSpec``, and a tenant of a
 ``TenantScheduler``. These are the only ``repro`` entry points the
 benchmark uses, beside the plan counters its metrics read.
 """
@@ -34,16 +34,33 @@ class Dataset:
 
 
 class Deployment:
-    """The system under test for one configuration."""
+    """The system under test for one configuration on ``chips`` chips: on
+    one, the M blocks as one vmapped program (``VmapRunner``); on more, one
+    block per chip through ``ShardMapRunner`` over a one-axis mesh of the
+    first ``chips`` devices, which needs M == chips."""
 
-    def __init__(self, cfg: dict):
+    def __init__(self, cfg: dict, chips: int = 1):
         from repro.core import covariance as cov
-        from repro.parallel.runner import VmapRunner
         self.cfg = cfg
         self.M = int(cfg["machines"])
+        self.devices = jax.devices()[:chips]
         self.params = data.hyperparameters(cfg["domain"])
         self.kfn = cov.make_spec(cfg["kernel"], impl=cfg["kernel_impl"])
-        self.runner = VmapRunner(M=self.M)
+        self.runner = self._runner(chips)
+
+    def _runner(self, chips: int):
+        from repro.parallel.runner import ShardMapRunner, VmapRunner
+        if chips == 1:
+            return VmapRunner(M=self.M)
+        if self.M != chips:
+            raise ValueError(
+                f"a {chips}-chip cell runs one block per chip "
+                f"(ShardMapRunner), so its configuration needs machines = "
+                f"{chips}; {self.cfg.get('name', 'this configuration')} "
+                f"has machines = {self.M}")
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((chips,), ("data",), devices=self.devices)
+        return ShardMapRunner(mesh=mesh, axis_name="data")
 
     # -- inputs ---------------------------------------------------------------
 

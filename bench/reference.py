@@ -51,16 +51,23 @@ def _chol(K):
         K + 1e-6 * scale * jnp.eye(K.shape[0], dtype=K.dtype))
 
 
-def _block(params, S, L, noise, p):
-    def one(a):
-        Xm, ym = a
+def _local(params, S, L, noise, p):
+    """One block's V = L⁻¹K_SD, C_L = chol Σ_{DD|S} and e = C_L⁻¹y."""
+    def one(Xm, ym):
         V = solve_triangular(L, kernel(params, S, Xm, p), lower=True)
         C_L = _chol(kernel(params, Xm, Xm, p)
                     + noise * jnp.eye(Xm.shape[0]) - mm(V.T, V, p))
+        return V, C_L, solve_triangular(C_L, ym, lower=True)
+    return one
+
+
+def _block(params, S, L, noise, p):
+    local = _local(params, S, L, noise, p)
+
+    def one(a):
+        V, C_L, e = local(*a)
         G = solve_triangular(C_L, V.T, lower=True).T          # (s, b)
-        e = solve_triangular(C_L, ym, lower=True)             # C_L⁻¹ y
-        Wy = solve_triangular(C_L, e, lower=True, trans=1)    # C⁻¹ y
-        return V, C_L, Wy, mm(G, G.T, p), mm(G, e, p)
+        return mm(G, G.T, p), mm(G, e, p)
     return one
 
 
@@ -68,7 +75,9 @@ def summaries(params, S, blocks, precision=PRECISION):
     """Eqs. (3)-(6) whitened: per block V = L⁻¹K_SD, C_L = chol Σ_{DD|S},
     G = V C_L⁻ᵀ; globally A = I + Σ G Gᵀ and c = L⁻¹ÿ, summed over every
     (Xb, yb) of ``blocks`` (the fitted data, then any assimilated waves).
-    Per-block factors are kept for the first entry (pPIC's local terms)."""
+    Only the global terms L, A_L and c are kept: a block's factors live
+    inside its own step of the map, so one block's b×b factor is alive at a
+    time (``pic`` recomputes them block by block)."""
     return _summaries(params, S, blocks, precision)
 
 
@@ -79,11 +88,9 @@ def _summaries(params, S, blocks, precision):
         L = _chol(kernel(params, S, S, precision))
         per = [jax.lax.map(_block(params, S, L, noise, precision), (Xb, yb))
                for Xb, yb in blocks]
-        A = jnp.eye(S.shape[0]) + sum(jnp.sum(p[3], 0) for p in per)
-        V, C_L, Wy = per[0][:3]
+        A = jnp.eye(S.shape[0]) + sum(jnp.sum(p[0], 0) for p in per)
         return dict(L=L, A_L=jnp.linalg.cholesky(A),
-                    c=sum(jnp.sum(p[4], 0) for p in per), V=V, C_L=C_L,
-                    Wy=Wy)
+                    c=sum(jnp.sum(p[1], 0) for p in per))
 
 
 def _global_terms(ref, W):
@@ -106,11 +113,18 @@ def _pitc(params, S, ref, U, precision):
                 - jnp.sum(v * v, 0) + jnp.sum(z * z, 0))
 
 
-def pic(params, S, Xb, ref, U, assign, precision=PRECISION):
-    """Eqs. (12)-(14), diagonal, query i answered by block ``assign[i]``.
-    With R = Σ_{UD|S} = K_UD - vᵀV and P = R C⁻¹: mean = Φ Σ̈⁻¹ÿ + P y and
-    var = k - |v|² - rowsum(R∘P) + |A_L⁻¹L⁻¹Φᵀ|², L⁻¹Φᵀ = v - V Pᵀ. Each
-    block is evaluated on its own queries only (U padded per block)."""
+def pic(params, S, blocks, ref, U, assign, precision=PRECISION,
+        devices=None):
+    """Eqs. (12)-(14), diagonal, query i answered by block ``assign[i]``
+    of ``blocks`` = (Xb, yb), the fitted data. With R = Σ_{UD|S} = K_UD -
+    vᵀV and P = R C⁻¹: mean = Φ Σ̈⁻¹ÿ + P y and var = k - |v|² -
+    rowsum(R∘P) + |A_L⁻¹L⁻¹Φᵀ|², L⁻¹Φᵀ = v - V Pᵀ. Each block is evaluated
+    on its own queries only (U padded per block), in a pass of its own that
+    recomputes the block's V, C_L and C⁻¹y and drops them before the next:
+    block m runs on ``devices[m % len(devices)]`` (default: the first
+    device), and each round waits for its blocks, so at most one block's
+    b×b factor is alive on a device."""
+    Xb, yb = (np.asarray(a) for a in blocks)
     U = np.asarray(U)
     assign = np.asarray(assign)
     M = int(Xb.shape[0])
@@ -122,18 +136,26 @@ def pic(params, S, Xb, ref, U, assign, precision=PRECISION):
         rows = np.flatnonzero(assign == m)
         Ub[m, :rows.size] = U[rows]
         slot[rows] = np.arange(rows.size)
-    means, vars_ = _pic(params, S, Xb, ref, jnp.asarray(Ub), precision)
-    means, vars_ = np.asarray(means), np.asarray(vars_)
+    devices = list(devices or jax.devices()[:1])
+    shared = {d: jax.device_put((params, S, ref), d) for d in devices}
+    answers = []
+    for r0 in range(0, M, len(devices)):
+        out = [_pic_block(*shared[d], *jax.device_put((Xb[m], yb[m], Ub[m]),
+                                                      d), precision)
+               for m, d in zip(range(r0, M), devices)]
+        answers += [[np.asarray(a) for a in o] for o in out]
+    means, vars_ = (np.stack(a) for a in zip(*answers))
     return means[assign, slot], vars_[assign, slot]
 
 
-@functools.partial(jax.jit, static_argnums=5)
-def _pic(params, S, Xb, ref, Ub, precision):
+@functools.partial(jax.jit, static_argnums=6)
+def _pic_block(params, S, ref, Xm, ym, Um, precision):
     sig2 = jnp.exp(2.0 * params["log_signal"])
     p = precision
-
-    def block(a):
-        Xm, V, C_L, Wy, Um = a
+    with jax.default_matmul_precision(precision):
+        noise = jnp.exp(2.0 * params["log_noise"])
+        V, C_L, e = _local(params, S, ref["L"], noise, p)(Xm, ym)
+        Wy = solve_triangular(C_L, e, lower=True, trans=1)    # C⁻¹ y
         v = solve_triangular(ref["L"], kernel(params, S, Um, p), lower=True)
         R = kernel(params, Um, Xm, p) - mm(v.T, V, p)           # (u, b)
         P = cho_solve((C_L, True), R.T).T                      # R C⁻¹
@@ -142,9 +164,19 @@ def _pic(params, S, Xb, ref, Ub, precision):
                 sig2 - jnp.sum(v * v, 0) - jnp.sum(R * P, 1)
                 + jnp.sum(z * z, 0))
 
-    with jax.default_matmul_precision(precision):
-        return jax.lax.map(block, (Xb, ref["V"], ref["C_L"], ref["Wy"],
-                                   Ub))
+
+def served(params, S, blocks, U, routed: bool, precision=PRECISION,
+           devices=None):
+    """(mean, var) at the rows of U of the posterior fitted on ``blocks``
+    = (Xb, yb), as the configuration serves it: pPIC, each query answered
+    by its nearest block (``nearest_block``), where ``routed``; else
+    pPITC."""
+    ref = summaries(params, S, [blocks], precision)
+    if routed:
+        return pic(params, S, blocks, ref, U, nearest_block(U, blocks[0]),
+                   precision, devices)
+    mean, var = pitc(params, S, ref, U, precision)
+    return np.asarray(mean), np.asarray(var)
 
 
 def nearest_block(U, Xb):

@@ -184,8 +184,8 @@ def _run(cell, seed, seconds, trace, device, peak_table, counter, control,
 
     spans = Spans(trace)
     kind = importlib.import_module(f"bench.traffic.{cell.workload['traffic']}")
-    traffic = kind.Traffic(Deployment(cell.config), cell.workload["params"],
-                           seed, spans)
+    traffic = kind.Traffic(Deployment(cell.config, cell.chips),
+                           cell.workload["params"], seed, spans)
     traffic.setup()
     c0, h0 = counter.compiles, counter.cache_hits
     setup_s = time.perf_counter() - PROCESS_START

@@ -90,16 +90,10 @@ class ServedTenant:
 
     def reference(self, precision: str = reference.PRECISION):
         """(mean, var) of the plain reference at every pool query."""
-        dep, params = self.dep, self.dep.params
-        Xb, yb = self.ds.blocks(dep.M)
-        ref = reference.summaries(params, self.S, [(Xb, yb)], precision)
-        if dep.routed:
-            assign = reference.nearest_block(self.pool, Xb)
-            return reference.pic(params, self.S, Xb, ref, self.pool, assign,
-                                 precision)
-        mean, var = reference.pitc(params, self.S, ref, self.pool,
-                                   precision)
-        return np.asarray(mean), np.asarray(var)
+        dep = self.dep
+        return reference.served(dep.params, self.S, self.ds.blocks(dep.M),
+                                self.pool, dep.routed, precision,
+                                dep.devices)
 
 
 class FlushMap:

@@ -3,9 +3,13 @@ look for a chip: the program served through its scheduler (or fitted, or
 assimilated) agrees with the plain reference; each fault a cell can have,
 planted in the timed path, turns ``correct`` false; and the control (the
 reference one precision step lower, in the program's place) reads at least
-three times what the program reads."""
+three times what the program reads. A cell on more than one chip runs in a
+process of its own with as many host CPU devices."""
 import dataclasses
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -17,15 +21,16 @@ from repro.parallel.runner import VmapRunner
 
 SMALL = dict(n=320, machines=4, support=32, kernel_impl="jnp")
 PARAMS = dict(pool=64, rate_per_s=200, clients=16, wave_rows=16, datasets=2)
-CELLS = ["aimpeak-ppic.poisson", "sarcos-ppitc.fit", "sarcos-ppitc.closed",
-         "sarcos-ppitc.waves"]
 SEED = 2**31 + 77
 # the poisson cell's files stay in bench/ while the cell is out of
-# BENCHMARK.json (PERF.md, open questions): its metrics as they were listed
+# BENCHMARK.json (PERF.md, open questions): its chips and metrics as they
+# were listed
 PARKED = {"aimpeak-ppic.poisson": (
-    "aimpeak-ppic", [("query_p99_ms", "ms"), ("setup_s", "s")],
+    "aimpeak-ppic", 1, [("query_p99_ms", "ms"), ("setup_s", "s")],
     [("gen_late_ms.p99", "ms"), ("host_ms_per_flush.p99", "ms"),
      ("routed_g0_share", "%"), ("device_idle.p99", "%")])}
+CELLS = [w["name"] for w in json.loads(
+    (run.ROOT / "BENCHMARK.json").read_text())["workloads"]] + sorted(PARKED)
 
 
 @pytest.fixture
@@ -41,10 +46,10 @@ def float32():
 def file_cell(name: str) -> run.Cell:
     if name not in PARKED:
         return run.load_cell(name)
-    config, e2e, layer = PARKED[name]
+    config, chips, e2e, layer = PARKED[name]
     read = lambda path: json.loads((run.BENCH / path).read_text())
     entries = lambda pairs: [{"name": n, "unit": u} for n, u in pairs]
-    return run.Cell(name, 1, read(f"workloads/{name}.json"),
+    return run.Cell(name, chips, read(f"workloads/{name}.json"),
                     read(f"configs/{config}.json"), entries(e2e),
                     entries(layer))
 
@@ -65,9 +70,34 @@ def go(name: str, *, seed: int = SEED, trace: bool = False, cell=None,
                         log=lambda s: None)
 
 
+def on_devices(n: int, script: str) -> dict:
+    """Run ``script`` in a fresh process that sees ``n`` host CPU devices
+    (float32, as the benchmark runs); returns the JSON object its last line
+    of output prints."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(run.ROOT),
+                                           str(run.ROOT / "src")]))
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                        f"platform_device_count={n}").strip()
+    r = subprocess.run([sys.executable, "-c", script], env=env, cwd=run.ROOT,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def go_cell(name: str) -> dict:
+    """``go(name)``, on as many devices as the cell asks for."""
+    chips = file_cell(name).chips
+    if chips == 1:
+        return go(name)
+    return on_devices(chips, "import json\n"
+                      "from bench.tests.test_bench_cells import go\n"
+                      f"print(json.dumps(go({name!r})))")
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_agrees_with_the_reference(name, float32):
-    r = go(name)
+    r = go_cell(name)
     assert r["correct"], r["checks"]
     assert r["attempted"] > 0 and r["failed"] == 0
     e2e = {m["name"] for m in file_cell(name).end_to_end}

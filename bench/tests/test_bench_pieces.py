@@ -156,6 +156,31 @@ def test_peaks_table_names_its_source():
     assert "TPU v5e" in json.loads(peaks.TABLE.read_text())["source"]
 
 
+@pytest.mark.parametrize("metric,ops", [
+    ("fit_mfu", lambda c: flops.ppitc_fit(c["n"], c["machines"],
+                                          c["support"], c["input_dim"])
+     * 270 / 30.0),
+    ("serve_mfu.qps", lambda c: flops.pitc_query(c["support"],
+                                                 c["input_dim"]) * 23000.0)])
+def test_peak_share_is_over_the_cells_chips(metric, ops):
+    """A share of the peak divides by every chip of the cell: on four it
+    reads a quarter of one chip's reading, which is ops/s over one peak."""
+    from types import SimpleNamespace
+
+    from bench import run
+    read = run.load_reader(metric)
+    cfg = {"n": 32000, "machines": 4, "support": 2048, "input_dim": 5}
+    traffic = SimpleNamespace(layer={"fits": 270, "elapsed": 30.0},
+                              end_to_end=lambda: {"queries_per_s": 23000.0})
+    peak = peaks.for_kind("TPU v5 lite")
+    share = {chips: read(run.Context(run.Cell("c", chips, {}, cfg, [], []),
+                                     traffic, None, None, peak))
+             for chips in (1, 4)}
+    assert share[1] == pytest.approx(
+        100.0 * ops(cfg) / peak["flops_bf16_per_s"], rel=1e-12)
+    assert share[4] == pytest.approx(share[1] / 4, rel=1e-12)
+
+
 # -- the trace reducer, on a trace recorded on a v5e ------------------------------
 
 TRACE = DATA / "tiny_v5e.xplane.pb"

@@ -65,20 +65,19 @@ class Traffic:
 
     def release(self) -> None:
         """Keep only what the check reads: each dataset's latest state
-        served on its pool."""
+        served on its pool, routed where the configuration serves routed."""
         self.served = {}
         for i, model in self.latest.items():
             U = self.sets[i].U
-            mean, var = model.predict_diag(U)
+            mean, var = (model.predict_routed_diag(U) if self.dep.routed
+                         else model.predict_diag(U))
             self.served[i] = (np.asarray(mean), np.asarray(var))
         self.latest = None
 
     def _reference(self, i: int, precision: str):
         dep, ds = self.dep, self.sets[i]
-        ref = reference.summaries(dep.params, self.S, [ds.blocks(dep.M)],
-                                  precision)
-        mean, var = reference.pitc(dep.params, self.S, ref, ds.U, precision)
-        return np.asarray(mean), np.asarray(var)
+        return reference.served(dep.params, self.S, ds.blocks(dep.M), ds.U,
+                                dep.routed, precision, dep.devices)
 
     def readings(self, precision: str = "highest") -> dict:
         """Every dataset's latest fitted state, served on its held-out pool,
